@@ -83,6 +83,22 @@ ARTIFACT = "BENCH_engine.json"
 _METRICS: dict[str, dict] = {}
 
 
+def _cpu_rehearsal_only(scenario: str):
+    """The scenarios that start child processes are CPU rehearsals: their
+    children run JAX on forced host devices (or, for the router, one
+    worker per device). On a TPU host they cannot work — a chip belongs
+    to one process, and a parent that has touched JAX holds it — so they
+    stop here, before any child starts. Checked from sysfs, without
+    loading the TPU library."""
+    from repro.serve.router import host_tpu_chips
+    if host_tpu_chips():
+        raise SystemExit(
+            f"{scenario} is a CPU rehearsal: its child processes cannot "
+            "share this host's TPU with this process. Run it with "
+            "JAX_PLATFORMS=cpu on a host without a TPU; chip_smoke.py "
+            "checks the sharded and spanning paths on the chip")
+
+
 def _median(values):
     return statistics.median(values)
 
@@ -344,6 +360,7 @@ def sharded_child(n_dev: int):
 
 
 def engine_sharded():
+    _cpu_rehearsal_only("engine_sharded")
     repo = pathlib.Path(__file__).resolve().parent.parent
     rates: dict[int, list[float]] = {d: [] for d in SHARD_DEVICES}
     digests: dict[int, set] = {d: set() for d in SHARD_DEVICES}
@@ -743,6 +760,7 @@ def engine_spanning():
     import shutil
     import tempfile
 
+    _cpu_rehearsal_only("engine_spanning")
     recs = {d: _span_spawn(["--spanning-child", str(d)], d)
             for d in SPAN_DEVICES}
     digests = {recs[d]["digest"] for d in SPAN_DEVICES}
@@ -1059,6 +1077,7 @@ def serving_smoke(artifact: str | None = None):
 
     from repro.serve.router import Router, WorkerHandle
 
+    _cpu_rehearsal_only("serving_smoke")
     tmp = pathlib.Path(tempfile.mkdtemp(prefix="bench_serving_smoke_"))
     worker_args = ["--lanes", "2", "--journal-every", "2"]
     handles = [WorkerHandle(i, tmp / f"w{i}", worker_args)

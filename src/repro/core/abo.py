@@ -535,7 +535,7 @@ def abo_minimize(
                 "the whole pass with no shard-boundary reset; use the jnp "
                 "path for spanning solves")
         from repro.kernels.coord_sweep.ops import abo_minimize_kernel
-        return abo_minimize_kernel(n, config=cfg, x0=x0, dtype=dtype)
+        return abo_minimize_kernel(n, config=cfg, x0=x[:n], dtype=dtype)
 
     probe_tile = _default_probe_tile(obj)
     state, fun = _abo_jit(x, obj, n, cfg, probe_tile, bnds)

@@ -29,12 +29,9 @@ def axis_linear_index(axes: Sequence[str]):
     inside a shard_map'd program. The single-axis case is the engine's
     sharded page pool ("which pool shard am I"); the multi-axis case is
     :func:`make_sharded_abo`'s coordinate offset on an N-d mesh."""
-    # jax < 0.5 has no lax.axis_size; psum(1, ax) is the classic form
-    axis_size = getattr(jax.lax, "axis_size",
-                        lambda ax: jax.lax.psum(1, ax))
     dev = jnp.zeros((), jnp.int32)
     for ax in axes:
-        dev = dev * axis_size(ax) + jax.lax.axis_index(ax)
+        dev = dev * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return dev
 
 
@@ -135,10 +132,8 @@ def make_sharded_abo(
         else:
             lam = jnp.ones((), aggs.dtype)
         half_width = 0.5 * cfg.resolved_shrink() ** pass_idx  # fractional
-        # aggs enters replicated; local commits make it device-varying.
-        # (jax < 0.7 has no lax.pcast / varying types — identity there)
-        pcast = getattr(jax.lax, "pcast", None)
-        aggs_v = pcast(aggs, axes, to="varying") if pcast else aggs
+        # aggs enters replicated; local commits make it device-varying
+        aggs_v = jax.lax.pcast(aggs, axes, to="varying")
         x_loc, d_aggs = _local_pass(obj, cfg, probe_tile, x_loc, aggs_v,
                                     half_width, pass_idx, lam, offset, n)
         # O(1) traffic: one all-reduce of the n_aggs scalar deltas.
@@ -146,8 +141,7 @@ def make_sharded_abo(
             d_aggs = jax.lax.psum(d_aggs, ax)
         return x_loc, aggs + d_aggs
 
-    from jax.experimental.shard_map import shard_map
-    step_sm = shard_map(
+    step_sm = jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(axes), P(), P()),
         out_specs=(P(axes), P()),

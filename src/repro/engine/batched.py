@@ -75,7 +75,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.abo import (ABOConfig, _block_step, _default_probe_tile,
@@ -286,8 +285,8 @@ def resize_pool_state(state: PoolState, lanes: int, pages: int,
                                          resize_slots(n_valid))
                 return pool, aggs, hist, pass_idx, n_valid
 
-            fn = jax.jit(shard_map(
-                local_resize, mesh=mesh, check_rep=False,
+            fn = jax.jit(jax.shard_map(
+                local_resize, mesh=mesh, check_vma=False,
                 in_specs=(P("pool", None), P(), P(), P(), P()),
                 out_specs=(P("pool", None), P(), P(), P(), P())),
                 donate_argnums=(0, 1, 2, 3, 4))
@@ -630,8 +629,8 @@ class PoolOps:
             span_specs = () if span is None else (
                 P(), P(), P("pool", None), P("pool", None),
                 P("pool", None, None), P("pool", None))
-            fn = jax.jit(shard_map(
-                run_local, mesh=self.mesh, check_rep=False,
+            fn = jax.jit(jax.shard_map(
+                run_local, mesh=self.mesh, check_vma=False,
                 in_specs=(_state_specs(), P(), P(), P())
                 + band_specs * n_bands
                 + (P("pool", None), P("pool", None, None))
@@ -690,8 +689,8 @@ class PoolOps:
                 st = self._write_lanes(state, lanes, pages, xr, ag, n_valid)
                 return self._reconcile_slots(st, owner, my)
 
-            fn = jax.jit(shard_map(
-                run_local, mesh=self.mesh, check_rep=False,
+            fn = jax.jit(jax.shard_map(
+                run_local, mesh=self.mesh, check_vma=False,
                 in_specs=(_state_specs(), P(), P("pool", None),
                           P("pool", None, None), P("pool", None),
                           P("pool", None), P("pool", None)),
@@ -740,8 +739,8 @@ class PoolOps:
                     n_valid[None])
                 return self._reconcile_slots(st, owner, my)
 
-            fn = jax.jit(shard_map(
-                run_local, mesh=self.mesh, check_rep=False,
+            fn = jax.jit(jax.shard_map(
+                run_local, mesh=self.mesh, check_vma=False,
                 in_specs=(_state_specs(), P(), P("pool"),
                           P("pool", None), P("pool", None), P("pool")),
                 out_specs=_state_specs()), donate_argnums=(0,))
@@ -818,8 +817,8 @@ class PoolOps:
             return dataclasses.replace(
                 st, aggs=st.aggs.at[lane].set(ag[0].astype(st.aggs.dtype)))
 
-        fn = jax.jit(shard_map(
-            run_local, mesh=self.mesh, check_rep=False,
+        fn = jax.jit(jax.shard_map(
+            run_local, mesh=self.mesh, check_vma=False,
             in_specs=(_state_specs(), P(), P(), P(), P(), P(), P(),
                       P("pool", None), P("pool", None),
                       P("pool", None), P("pool", None, None),
@@ -889,8 +888,8 @@ class PoolOps:
 
             # repro: allow[RPR005] sharded finalize: same read-only contract
             # as the unsharded branch — state must stay live for stepping
-            fn = jax.jit(shard_map(
-                run_local, mesh=self.mesh, check_rep=False,
+            fn = jax.jit(jax.shard_map(
+                run_local, mesh=self.mesh, check_vma=False,
                 in_specs=(_state_specs(), P(), P("pool", None),
                           P("pool", None, None)),
                 out_specs=(P(), P(), P())))
@@ -927,8 +926,8 @@ class PoolOps:
 
         # repro: allow[RPR005] read-only like finalize: the pool must stay
         # live for stepping, so no donation
-        fn = jax.jit(shard_map(
-            run_local, mesh=self.mesh, check_rep=False,
+        fn = jax.jit(jax.shard_map(
+            run_local, mesh=self.mesh, check_vma=False,
             in_specs=(_state_specs(), P(), P(),
                       P("pool", None, None)),
             out_specs=(P(), P(), P())))

@@ -756,6 +756,20 @@ class LanePool:
         return (vs, t_pad, ts, ppt), span_args, span_bytes
 
 
+def too_few_devices_message(wanted: int, devices) -> str:
+    """Why ``devices=wanted`` cannot be met. On a TPU, D counts chips; a
+    D-device mesh on the CPU is the rehearsal, with forced host devices."""
+    head = f"devices={wanted} but JAX sees {len(devices)} " \
+           f"{devices[0].platform} device(s)"
+    if devices[0].platform == "cpu":
+        return (f"{head}: this is the CPU rehearsal of a mesh — force "
+                f"{wanted} host devices with XLA_FLAGS=--xla_force_host_"
+                f"platform_device_count={wanted}, set before jax "
+                "initializes; on a TPU host, devices counts chips")
+    return (f"{head}: devices counts this host's chips; run on a host "
+            f"with {wanted} or more")
+
+
 class SolveEngine:
     """Serve many concurrent ABO jobs through shared jitted sweeps.
 
@@ -796,11 +810,7 @@ class SolveEngine:
         if self.n_dev > 1:
             avail = jax.devices()
             if len(avail) < self.n_dev:
-                raise ValueError(
-                    f"devices={self.n_dev} but only {len(avail)} JAX "
-                    "device(s) are visible; on CPU, launch with "
-                    "XLA_FLAGS=--xla_force_host_platform_device_count="
-                    f"{self.n_dev} (must be set before jax initializes)")
+                raise ValueError(too_few_devices_message(self.n_dev, avail))
             self.mesh = Mesh(np.array(avail[:self.n_dev]), ("pool",))
         else:
             self.mesh = None

@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.abo import ABOConfig, ABOResult
-from repro.kernels.coord_sweep.kernel import AGG_LANES, sweep_pass_kernel
+from repro.kernels.coord_sweep.kernel import (AGG_LANES, LANES,
+                                              sweep_pass_kernel)
 from repro.objectives.griewank import GRIEWANK
 
 
@@ -25,12 +26,14 @@ def pack_aggs(aggs3: jnp.ndarray) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("m", "n_valid", "half_width", "lam",
-                                    "is_first", "interpret"))
-def sweep_pass(x2d, aggs, *, m, n_valid, half_width, lam, is_first,
+                   static_argnames=("block", "m", "n_valid", "half_width",
+                                    "lam", "is_first", "interpret"))
+def sweep_pass(x2d, aggs, *, block, m, n_valid, half_width, lam, is_first,
                interpret=False):
+    """One kernel pass over the lane-dense ``(n_pad / LANES, LANES)``
+    solution, ``block`` coordinates per Jacobi tile."""
     return sweep_pass_kernel(
-        x2d, aggs, m=m, n_valid=n_valid, lower=GRIEWANK.lower,
+        x2d, aggs, block=block, m=m, n_valid=n_valid, lower=GRIEWANK.lower,
         upper=GRIEWANK.upper, half_width=half_width, lam=lam,
         is_first=is_first, interpret=interpret)
 
@@ -41,12 +44,12 @@ def abo_minimize_kernel(
     config: ABOConfig | None = None,
     x0: jnp.ndarray | None = None,
     dtype=jnp.float32,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> ABOResult:
-    """Griewank ABO with the Pallas sweep kernel (interpret=True on CPU)."""
+    """Griewank ABO with the Pallas sweep kernel. ``interpret=True`` runs
+    the kernel through the Pallas interpreter (any backend, tests); the
+    default compiles it for the TPU and fails anywhere else."""
     cfg = config or ABOConfig()
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
     bsz, m = cfg.block_size, cfg.samples_per_pass
     n_pad = -(-n // bsz) * bsz
     if x0 is None:
@@ -54,7 +57,7 @@ def abo_minimize_kernel(
                      + 0.6180339887 * (GRIEWANK.upper - GRIEWANK.lower), dtype)
     else:
         x = jnp.zeros((n_pad,), dtype).at[:n].set(jnp.asarray(x0, dtype))
-    x2d = x.reshape(-1, bsz)
+    x2d = x.reshape(-1, LANES)
     aggs = pack_aggs(GRIEWANK.aggregates(x, n, agg_dtype=jnp.float32))
 
     shrink = cfg.resolved_shrink()
@@ -65,8 +68,9 @@ def abo_minimize_kernel(
                if cfg.coupling_schedule == "linear" and cfg.n_passes > 1
                else 1.0)
         x2d, aggs = sweep_pass(
-            x2d, aggs, m=m, n_valid=n, half_width=float(w0 * shrink ** p),
-            lam=float(lam), is_first=(p == 0), interpret=interpret)
+            x2d, aggs, block=bsz, m=m, n_valid=n,
+            half_width=float(w0 * shrink ** p), lam=float(lam),
+            is_first=(p == 0), interpret=interpret)
         hist.append(GRIEWANK.combine(aggs[0, :3]))
 
     x = x2d.reshape(-1)[:n]

@@ -10,7 +10,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.coord_sweep.kernel import _combine, _griewank_planes
+from repro.kernels.coord_sweep.kernel import (LANES, _combine,
+                                              _griewank_planes, grid_deltas,
+                                              tile_sum)
 
 
 def sweep_pass_ref(
@@ -41,9 +43,9 @@ def sweep_pass_ref(
         else:
             center = xb
             hw = half_width
-        offs = jlane.astype(dt) * (2.0 / (m - 2)) - 1.0
-        cands = jnp.clip(center[:, None] + hw * offs, lower, upper)
-        cands = jnp.where(jlane == m - 1, xb[:, None], cands)
+        grid = jnp.clip(center[:, None] + grid_deltas(hw, m, dt)[None, :],
+                        lower, upper)
+        cands = jnp.concatenate([grid, xb[:, None]], axis=1)
         valid = bidx < n_valid
         cands = jnp.where(valid, cands, xb[:, None])
 
@@ -57,9 +59,10 @@ def sweep_pass_ref(
         sel = jnp.argmin(f, axis=1)
         onehot = (jlane == sel[:, None]).astype(dt)
         x_sel = jnp.sum(cands * onehot, axis=1)
-        s1 = s0 + jnp.sum(ds * onehot)
-        l1 = l0 + jnp.sum(dl * onehot)
-        k1 = k0 + jnp.sum(dk * onehot)
+        # the kernel's commit reduction: selected deltas in the
+        # lane-dense (block / LANES, LANES) tile, summed rows then lanes
+        s1, l1, k1 = (a0 + tile_sum(jnp.sum(d * onehot, axis=1).reshape(
+            -1, LANES))[0, 0] for a0, d in ((s0, ds), (l0, dl), (k0, dk)))
         accept = _combine(s1, l1, k1, lam) <= _combine(s0, l0, k0, lam)
 
         x2d = x2d.at[blk].set(jnp.where(accept, x_sel, xb))

@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.coord_sweep.kernel import LANES
 from repro.kernels.griewank.kernel import griewank_aggregates_kernel
 from repro.objectives.griewank import GRIEWANK
 
@@ -16,6 +17,7 @@ def griewank_eval(x: jnp.ndarray, *, chunk: int = 4096,
     """Scalar Griewank value of a flat vector via the streaming kernel."""
     n = x.shape[0]
     n_pad = -(-n // chunk) * chunk
-    x2d = jnp.zeros((n_pad,), x.dtype).at[:n].set(x).reshape(-1, chunk)
-    aggs = griewank_aggregates_kernel(x2d, n_valid=n, interpret=interpret)
+    x2d = jnp.zeros((n_pad,), x.dtype).at[:n].set(x).reshape(-1, LANES)
+    aggs = griewank_aggregates_kernel(x2d, chunk=chunk, n_valid=n,
+                                      interpret=interpret)
     return GRIEWANK.combine(aggs[0, :3])

@@ -202,8 +202,6 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool, optimizer="adamw",
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):       # jax < 0.5: one dict per device
-            cost = cost[0] if cost else {}
         coll = collective_bytes(compiled.as_text())
 
     n_dev = mesh.devices.size

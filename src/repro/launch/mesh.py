@@ -8,11 +8,7 @@ import jax
 
 
 def _axis_types_kw(ndim: int) -> dict:
-    # jax >= 0.5 wants explicit AxisType; 0.4.x has no such argument
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * ndim}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * ndim}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -21,8 +21,9 @@ one compiled executable family — no pad rungs, no admission gating, no
 padded compute beyond the last block's tail. Per-job results are
 bit-identical to standalone ``abo_minimize`` at any lane/page layout.
 With ``--devices D`` the page pools shard across the first D JAX devices
-(on CPU: launch with XLA_FLAGS=--xla_force_host_platform_device_count=D
-so D host devices exist before jax initializes); lanes place whole per
+(on a TPU host, D chips; the CPU rehearsal launches with
+XLA_FLAGS=--xla_force_host_platform_device_count=D so D host devices
+exist before jax initializes); lanes place whole per
 device, stepping is donated and zero-copy, and results stay bit-identical
 at every device count — a snapshot cut on one D resumes on another
 (reshard on load). ``--span PAGES`` additionally stripes any lane larger
@@ -122,7 +123,7 @@ import time
 
 from repro.core.abo import ABOConfig
 from repro.engine.jobs import JobSpec
-from repro.engine.scheduler import SolveEngine
+from repro.engine.scheduler import SolveEngine, too_few_devices_message
 from repro.engine.service import SolveService
 
 
@@ -187,10 +188,11 @@ def main(argv=None):
                     help="shard each family's page pool across the first "
                          "D JAX devices (lanes place whole onto the least-"
                          "loaded device; results stay bit-identical at any "
-                         "D). On CPU, launch with XLA_FLAGS="
-                         "--xla_force_host_platform_device_count=D to "
-                         "expose D host devices. On resume, D overrides "
-                         "the snapshot's device count (reshard on load)")
+                         "D). On a TPU host D counts chips. The CPU "
+                         "rehearsal of a mesh forces D host devices with "
+                         "XLA_FLAGS=--xla_force_host_platform_device_"
+                         "count=D. On resume, D overrides the snapshot's "
+                         "device count (reshard on load)")
     ap.add_argument("--span", type=int, default=None, metavar="PAGES",
                     help="spanning lanes: stripe any lane whose page count "
                          "exceeds PAGES across the device mesh instead of "
@@ -327,11 +329,9 @@ def main(argv=None):
             ap.error(f"--devices must be >= 1, got {args.devices}")
         if args.devices > len(jax.devices()):
             # usage error, not an engine traceback: the fix is the launch
-            # environment (XLA_FLAGS predates jax init), not the request
-            ap.error(f"--devices {args.devices} but only "
-                     f"{len(jax.devices())} JAX device(s) are visible; "
-                     "launch with XLA_FLAGS=--xla_force_host_platform_"
-                     f"device_count={args.devices}")
+            # environment, not the request
+            ap.error("--" + too_few_devices_message(args.devices,
+                                                    jax.devices()))
     if args.span is not None:
         if args.span < 1:
             ap.error(f"--span must be >= 1, got {args.span}")
@@ -526,4 +526,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -74,8 +74,6 @@ def hlo_bytes_accessed(fn, *args) -> float | None:
     live buffers are safe to pass."""
     try:
         cost = fn.lower(*args).compile().cost_analysis()
-        if isinstance(cost, list):       # jax < 0.5 returns [dict]
-            cost = cost[0] if cost else {}
         val = cost.get("bytes accessed")
         return float(val) if val is not None else None
     except Exception:                    # noqa: BLE001 — diagnostic only

@@ -26,7 +26,8 @@ The code catalog (HTTP status -> codes):
 503    ``memory_budget`` (engine shed), ``saturated`` (request queue
        full), ``deadline`` (request deadline passed while waiting),
        ``shutting_down``, ``worker_unavailable`` (router: worker down,
-       restart in progress)
+       restart in progress), ``engine_failed`` (the engine's stepper
+       died; the worker exits non-zero and its supervisor restarts it)
 500    ``internal`` (anything unmapped — a bug, never policy)
 =====  ===============================================================
 

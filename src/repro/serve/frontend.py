@@ -41,6 +41,13 @@ Graceful shutdown: ``begin_shutdown()`` is signal-safe; in-flight
 replies complete (long-polls answer 503 ``shutting_down``), the stepper
 stops at a step boundary, a final snapshot lands, and ``serve()``
 returns for a clean exit 0.
+
+Engine failure: if a step raises (a device OOM, a compiler error), the
+stepper records the error and stops. From then on ``/healthz`` answers
+503 ``engine_failed`` and so does every engine request, parked
+long-polls included; ``serve()`` shuts the listener down, skips the
+final snapshot (the journal holds every acked input) and raises, so the
+serving process exits non-zero instead of acking work it can never run.
 """
 from __future__ import annotations
 
@@ -106,6 +113,8 @@ class Frontend:
         self._done = threading.Condition()   # long-poll waiters
         self._stop_stepper = threading.Event()
         self._stopping = False
+        self._serving = False                # serve() owns the listener
+        self.engine_error: Exception | None = None   # the step that died
         self._step_ewma = 0.05               # recent step wall seconds
         self._health: dict = {"steps": 0, "active_lanes": 0, "queued": 0}
         m = service.engine.metrics
@@ -170,9 +179,13 @@ class Frontend:
                     # chaos: a worker_crash fault kills/raises HERE, at
                     # the step boundary — exactly where a real OOM-kill
                     # lands, after durable journal appends
-                    eng.faults.trip("worker_crash")
                     t0 = time.perf_counter()
-                    self.service.step()
+                    try:
+                        eng.faults.trip("worker_crash")
+                        self.service.step()
+                    except Exception as e:   # noqa: BLE001 — reported
+                        self._engine_failed(e)
+                        return
                     dt = time.perf_counter() - t0
                     self._step_ewma = 0.7 * self._step_ewma + 0.3 * dt
                     self._sample_health()
@@ -190,6 +203,24 @@ class Frontend:
                     continue
                 self._wake.wait(backoff)
                 backoff = min(backoff * 2, cfg.idle_max_s)
+
+    def _engine_failed(self, err: Exception):
+        """The stepper's last act: record why, wake every parked
+        long-poll, and (under :meth:`serve`) stop the listener so the
+        process exits non-zero."""
+        import traceback
+        traceback.print_exception(err)
+        print(f"[serve] engine failed: {err!r}", flush=True)
+        self.engine_error = err
+        with self._done:
+            self._done.notify_all()
+        if self._serving:
+            self.begin_shutdown("engine failed")
+
+    def _refuse_if_failed(self):
+        if self.engine_error is not None:
+            raise ApiError(503, "engine_failed",
+                           f"engine failed: {self.engine_error!r}")
 
     # ----------------------------------------------------------- admission
     def retry_after_s(self, memory: bool = False) -> int:
@@ -248,6 +279,7 @@ class Frontend:
         self._c_longpoll.inc()
         end = time.monotonic() + min(wait_s, self.cfg.wait_max_s)
         while True:
+            self._refuse_if_failed()
             with self.engine_slot(deadline):
                 out = fetch(job_id)
             if out.get("status") in _TERMINAL \
@@ -296,7 +328,7 @@ class Frontend:
                     break
             time.sleep(0.01)
         engine = self.service.engine
-        if engine.ckpt is not None:
+        if engine.ckpt is not None and self.engine_error is None:
             # stepper stopped + in-flight drained: the lock is a
             # formality, the snapshot a step-boundary-consistent image
             with self._engine_lock:
@@ -308,7 +340,10 @@ class Frontend:
         self.httpd.server_close()
 
     def serve(self):
-        """Blocking: stepper + listener until shutdown, then finalize."""
+        """Blocking: stepper + listener until shutdown, then finalize.
+        Raises ``RuntimeError`` (from the step's error) when the engine
+        failed, so the serving process exits non-zero."""
+        self._serving = True
         self.stepper_thread.start()
         host, port = self.httpd.server_address[:2]
         print(f"[serve] listening on http://{host}:{port}", flush=True)
@@ -316,6 +351,8 @@ class Frontend:
             self.httpd.serve_forever()
         finally:
             self.finalize()
+        if self.engine_error is not None:
+            raise RuntimeError("engine stepper failed") from self.engine_error
 
 
 def _make_handler(fe: Frontend):
@@ -470,6 +507,7 @@ def _make_handler(fe: Frontend):
             return min(wait, cfg.wait_max_s)
 
         def _refuse_if_stopping(self):
+            fe._refuse_if_failed()
             if fe._stopping:
                 raise ApiError(503, "shutting_down",
                                "server is shutting down",
@@ -494,6 +532,10 @@ def _make_handler(fe: Frontend):
             # liveness endpoints FIRST and lock-free: a probe must
             # answer even while the engine grinds a long fused step
             if url.path == "/healthz":
+                if fe.engine_error is not None:
+                    return self._reply(
+                        {"status": "engine_failed",
+                         "error": repr(fe.engine_error), **fe._health}, 503)
                 status = "shutting_down" if fe._stopping else "ok"
                 return self._reply({"status": status, **fe._health})
             if url.path == "/metrics":

@@ -37,12 +37,20 @@ its FIRST life only (e.g. ``0:worker_crash:nth=3:kind=kill`` — the CI
 smoke kills worker 0 at its 3rd step and asserts zero lost jobs);
 respawns come up clean, which is what makes the experiment converge.
 
+On a TPU host each worker owns one chip: worker ``i`` starts with
+libtpu's per-process visibility variables set to chip ``i``
+(:func:`chip_env`), so N workers never fight over one chip or over
+libtpu's one-process-per-host lock. A worker whose chip does not exist
+exits at startup with ``EXIT_NO_CHIP`` and a message saying so; the
+supervisor retires it instead of respawning it forever.
+
 Stdlib + repro.obs/repro.serve only — importing this module never pays
 for jax; the workers do that in their own processes.
 """
 from __future__ import annotations
 
 import argparse
+import glob
 import json
 import math
 import os
@@ -57,8 +65,42 @@ import zlib
 from repro.obs.metrics import MetricsRegistry
 from repro.serve.errors import ApiError
 from repro.serve.limits import TenantTable
+from repro.serve.worker import EXIT_NO_CHIP
 
 _WORKER_TIMEOUT = 120.0     # first bind can pay a cold jax import
+
+# PCI identity of TPU chips — vendor Google, and the device ids of TPU
+# v3 through TPU7x — as JAX's own chip count reads them from sysfs
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICES = {"0x0027", "0x0056", "0x005e", "0x0062", "0x0063",
+                    "0x006f", "0x0076"}
+# libtpu's per-process service port; one per worker on a shared host
+_TPU_PORT_BASE = 8476
+
+
+def host_tpu_chips() -> int:
+    """TPU chips attached to this host (0 off a TPU host), from sysfs —
+    without loading JAX or the TPU library."""
+    n = 0
+    for vendor in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        dev = pathlib.Path(vendor).parent
+        try:
+            if (pathlib.Path(vendor).read_text().strip() == _GOOGLE_PCI_VENDOR
+                    and (dev / "device").read_text().strip()
+                    in _TPU_PCI_DEVICES):
+                n += 1
+        except OSError:
+            continue
+    return n
+
+
+def chip_env(index: int) -> dict[str, str]:
+    """libtpu's per-process chip visibility for worker ``index``: the one
+    chip ``index``, as a one-chip slice with its own service port."""
+    return {"TPU_VISIBLE_CHIPS": str(index),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(_TPU_PORT_BASE + index)}
 
 
 class WorkerHandle:
@@ -67,6 +109,7 @@ class WorkerHandle:
     def __init__(self, index: int, ckpt_dir: str | pathlib.Path,
                  spawn_args: list[str]):
         self.index = index
+        self.retired = False         # exited EXIT_NO_CHIP: never respawn
         self.name = f"w{index}"
         self.ckpt_dir = pathlib.Path(ckpt_dir)
         self.spawn_args = list(spawn_args)
@@ -96,7 +139,10 @@ class WorkerHandle:
                    "--port", "0", "--port-file", str(self.port_file),
                    *self.spawn_args, *extra_args]
             self.last_spawn = time.monotonic()
-            self.proc = subprocess.Popen(cmd, env=os.environ.copy())
+            env = os.environ.copy()
+            if host_tpu_chips():         # one chip per worker
+                env.update(chip_env(self.index))
+            self.proc = subprocess.Popen(cmd, env=env)
         deadline = time.monotonic() + _WORKER_TIMEOUT
         while time.monotonic() < deadline:
             if self.proc.poll() is not None:
@@ -202,9 +248,19 @@ class Router:
                 if self._stop.is_set():
                     return
                 if w.proc is not None and not w.alive():
+                    if w.retired:
+                        continue
+                    code = w.proc.returncode
+                    if code == EXIT_NO_CHIP:
+                        # a respawn finds no chip either: retire it
+                        w.retired = True
+                        print(f"[router] {w.name} has no chip of its own "
+                              "(exit EXIT_NO_CHIP) — retired, not "
+                              "respawned; run at most one worker per chip",
+                              flush=True)
+                        continue
                     if now < w.not_before:
                         continue        # still in backoff
-                    code = w.proc.returncode
                     uptime = now - w.last_spawn
                     w.restarts += 1
                     self._c_restarts(

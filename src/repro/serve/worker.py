@@ -22,7 +22,13 @@ worker death boring:
 4. **SIGTERM is a clean exit.** In-flight replies finish, the stepper
    stops at a step boundary, a final snapshot lands, exit 0. SIGKILL
    (or an injected ``worker_crash`` kill fault) is the torn case the
-   journal exists for.
+   journal exists for. An engine failure (a step that raises) exits
+   non-zero, and the router respawns the worker.
+5. **One chip per worker.** On a TPU host the router sets
+   ``TPU_VISIBLE_CHIPS`` (see ``router.chip_env``); a worker that then
+   finds no TPU — its chip index is past the host's chips — exits
+   ``EXIT_NO_CHIP`` at startup with a message, and the router does not
+   respawn it.
 
 The worker serves unauthenticated localhost HTTP: auth, rate limits,
 and quotas live at the router in a multi-worker deployment (or at this
@@ -33,6 +39,11 @@ from __future__ import annotations
 import argparse
 import os
 import pathlib
+import sys
+
+# exit status of a worker started for a chip it cannot use (sysexits'
+# EX_CONFIG): the router retires such a worker instead of respawning it
+EXIT_NO_CHIP = 78
 
 
 def _write_port_file(path: str, port: int):
@@ -81,6 +92,22 @@ def main(argv=None) -> int:
 
     if args.journal_every < 1:
         ap.error(f"--journal-every must be >= 1, got {args.journal_every}")
+
+    chip = os.environ.get("TPU_VISIBLE_CHIPS")
+    if chip is not None:
+        # the router gave this worker a chip: it must be a TPU JAX sees,
+        # never a silent fall back to the host CPU
+        import jax
+        try:
+            platform = jax.devices()[0].platform
+        except RuntimeError as e:
+            platform = f"none ({e})"
+        if platform != "tpu":
+            print(f"[worker] TPU_VISIBLE_CHIPS={chip} but JAX finds no TPU "
+                  f"(platform: {platform}): this host has no such chip — "
+                  "run at most one worker per chip", file=sys.stderr,
+                  flush=True)
+            return EXIT_NO_CHIP
 
     # 1. repair torn on-disk state BEFORE the engine opens it
     from repro.checkpoint.fsck import fsck
@@ -145,4 +172,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

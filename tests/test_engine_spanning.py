@@ -197,7 +197,6 @@ def test_owner_select_properties_subprocess():
         import jax.numpy as jnp
         import numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.core.sharded import axis_linear_index, owner_select
 
         D = len(jax.devices())
@@ -208,8 +207,8 @@ def test_owner_select_properties_subprocess():
             def body(x, owner):
                 my = axis_linear_index(('pool',))
                 return owner_select(x, owner, my, 'pool')
-            f = shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                          out_specs=P(), check_rep=False)
+            f = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                              out_specs=P(), check_vma=False)
             return np.asarray(jax.jit(f)(jax.device_put(x, rep),
                                          jax.device_put(owner, rep)))
 

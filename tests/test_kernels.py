@@ -1,9 +1,11 @@
 """Per-kernel shape/dtype sweeps, interpret-mode vs pure-jnp oracle."""
+import dataclasses
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.core import ABOConfig
+from repro.core import ABOConfig, abo_minimize
 from repro.kernels.coord_sweep.ops import (abo_minimize_kernel, pack_aggs,
                                            sweep_pass)
 from repro.kernels.coord_sweep.ref import sweep_pass_ref
@@ -30,9 +32,11 @@ def test_coord_sweep_vs_ref(n_blocks, block, m, lam, is_first, rng):
     aggs = pack_aggs(GRIEWANK.aggregates(x2d.reshape(-1), n,
                                          agg_dtype=jnp.float32))
     kw = dict(m=m, n_valid=n, half_width=37.5, lam=lam, is_first=is_first)
-    xk, ak = sweep_pass(x2d, aggs, interpret=True, **kw)
+    xk, ak = sweep_pass(x2d.reshape(-1, 128), aggs, block=block,
+                        interpret=True, **kw)
     xr, ar = sweep_pass_ref(x2d, aggs, lower=-600.0, upper=600.0, **kw)
-    np.testing.assert_allclose(np.asarray(xk), np.asarray(xr), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(xk).reshape(n_blocks, block),
+                               np.asarray(xr), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(ak[0, :3]), np.asarray(ar[0, :3]),
                                rtol=2e-4, atol=1e-5)
 
@@ -43,8 +47,9 @@ def test_coord_sweep_padding_frozen(rng):
                                   (n_blocks, block)).astype(np.float32))
     aggs = pack_aggs(GRIEWANK.aggregates(x2d.reshape(-1), n,
                                          agg_dtype=jnp.float32))
-    xk, _ = sweep_pass(x2d, aggs, m=16, n_valid=n, half_width=50.0,
-                       lam=1.0, is_first=False, interpret=True)
+    xk, _ = sweep_pass(x2d.reshape(-1, 128), aggs, block=block, m=16,
+                       n_valid=n, half_width=50.0, lam=1.0, is_first=False,
+                       interpret=True)
     np.testing.assert_array_equal(np.asarray(xk).reshape(-1)[n:],
                                   np.asarray(x2d).reshape(-1)[n:])
 
@@ -54,6 +59,18 @@ def test_kernel_abo_end_to_end():
         4096, config=ABOConfig(block_size=512, samples_per_pass=64),
         interpret=True)
     assert r.fun < 1e-6
+
+
+def test_kernel_path_never_picks_interpret_itself():
+    """Off the TPU the kernel path fails loudly unless the caller asks for
+    the interpreter — it never falls back to it on its own, and neither
+    does ``abo_minimize(use_kernel=True)`` above it."""
+    cfg = ABOConfig(block_size=1024, samples_per_pass=8, n_passes=2)
+    with pytest.raises(ValueError, match="interpret"):
+        abo_minimize_kernel(2048, config=cfg)
+    with pytest.raises(ValueError, match="interpret"):
+        abo_minimize(GRIEWANK, 2048, config=dataclasses.replace(
+            cfg, use_kernel=True))
 
 
 # ---------------------------------------------------------------------------
@@ -69,7 +86,8 @@ def test_griewank_kernel_vs_ref(n, chunk, rng):
 
 def test_griewank_kernel_aggs_vs_ref(rng):
     x2d = jnp.asarray(rng.uniform(-600, 600, (4, 256)).astype(np.float32))
-    got = griewank_aggregates_kernel(x2d, n_valid=1000, interpret=True)
+    got = griewank_aggregates_kernel(x2d.reshape(-1, 128), chunk=256,
+                                     n_valid=1000, interpret=True)
     want = griewank_aggregates_ref(x2d, n_valid=1000)
     np.testing.assert_allclose(np.asarray(got[0, :3]),
                                np.asarray(want[0, :3]), rtol=1e-5, atol=1e-5)

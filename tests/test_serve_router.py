@@ -25,7 +25,8 @@ from repro.core import ABOConfig, abo_minimize
 from repro.objectives import OBJECTIVES
 from repro.serve.errors import ApiError
 from repro.serve.router import (Router, WorkerHandle, _parse_inject_worker,
-                                _stamp_worker, main as router_main)
+                                _stamp_worker, chip_env, main as router_main)
+from repro.serve.worker import EXIT_NO_CHIP
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 CFG = {"samples_per_pass": 12, "n_passes": 3}
@@ -113,6 +114,50 @@ def test_router_import_is_jax_free():
          "assert 'jax' not in sys.modules, 'router imported jax'"],
         env=env, capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_chip_env_gives_each_worker_its_own_chip():
+    envs = [chip_env(i) for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+
+
+def test_worker_without_its_chip_exits_no_chip(tmp_path):
+    """A worker told to use a chip JAX cannot see exits EXIT_NO_CHIP at
+    startup, saying why — it never serves on the host CPU instead."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(REPO / "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    env.update(chip_env(3))
+    proc = subprocess.run(
+        [sys.executable, "-m", "repro.serve.worker",
+         "--ckpt-dir", str(tmp_path / "w3")],
+        env=env, capture_output=True, text=True, timeout=120)
+    assert proc.returncode == EXIT_NO_CHIP, proc.stderr[-2000:]
+    assert "TPU_VISIBLE_CHIPS=3" in proc.stderr
+    assert not (tmp_path / "w3" / "port").exists()
+
+
+def test_supervisor_retires_worker_without_chip(tmp_path):
+    """The supervisor respawns crashed workers, but one that exited
+    EXIT_NO_CHIP would find no chip on every life: it is retired."""
+    w = WorkerHandle(0, tmp_path / "w0", [])
+    w.proc = subprocess.Popen([sys.executable, "-c",
+                               f"raise SystemExit({EXIT_NO_CHIP})"])
+    w.proc.wait(timeout=60)
+    spawns = []
+    w.spawn = lambda *a: spawns.append(a)
+    rt = Router([w], port=0, probe_s=0.01)
+    try:
+        rt.supervisor_thread.start()
+        time.sleep(0.3)
+    finally:
+        rt._stop.set()
+        rt.supervisor_thread.join(timeout=10)
+        rt.httpd.server_close()
+    assert not rt.supervisor_thread.is_alive()
+    assert w.retired and spawns == []
 
 
 # ------------------------------------------------------------- chaos e2e

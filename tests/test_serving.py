@@ -498,6 +498,61 @@ def test_submit_rejects_unknown_objective_as_400():
         _stop(fe)
 
 
+def test_dead_stepper_fails_healthz_and_requests():
+    """A step that raises kills the stepper: /healthz answers 503
+    engine_failed, and so does every engine request — a live listener
+    never fronts a dead engine that would ack work and never run it."""
+    from repro.engine.faults import parse_fault_spec
+    svc = SolveService(lanes=1, faults=parse_fault_spec("fused_step:nth=1"))
+    fe = _start(svc)
+    port = fe.httpd.server_address[1]
+    fe.stepper_thread.start()
+    try:
+        st, sub, _ = _req(port, "POST", "/submit", _submit_body())
+        assert st == 200
+        fe.stepper_thread.join(timeout=60)
+        assert not fe.stepper_thread.is_alive()
+        st, out, _ = _req(port, "GET", "/healthz")
+        assert st == 503 and out["status"] == "engine_failed", out
+        assert "fused_step" in out["error"]
+        st, out, _ = _req(port, "GET",
+                          f"/result?job_id={sub['job_id']}&wait=5")
+        assert st == 503 and out["code"] == "engine_failed", out
+        st, out, _ = _req(port, "POST", "/submit", _submit_body(seed=1))
+        assert st == 503 and out["code"] == "engine_failed", out
+    finally:
+        _stop(fe)
+
+
+def test_dead_stepper_http_process_exits_nonzero(tmp_path):
+    """``solve_server --http``: an engine failure ends the process with a
+    non-zero status (a supervisor sees a crash, not a healthy zombie)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(REPO / "src")
+    env.setdefault("JAX_PLATFORMS", "cpu")
+    port_file = tmp_path / "port"
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "repro.launch.solve_server",
+         "--http", "0", "--port-file", str(port_file), "--lanes", "1",
+         "--inject", "fused_step:nth=1"],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+    try:
+        deadline = time.monotonic() + 120
+        while not port_file.exists() and time.monotonic() < deadline:
+            assert proc.poll() is None, proc.communicate()[1][-3000:]
+            time.sleep(0.1)
+        st, _, _ = _req(int(port_file.read_text()), "POST", "/submit",
+                        _submit_body())
+        assert st == 200
+        out, err = proc.communicate(timeout=120)
+        assert proc.returncode not in (0, None), (out, err[-3000:])
+        assert "engine failed" in out
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+            proc.communicate()
+
+
 # ---------------------------------------------------------- shutdown path
 def test_sigterm_with_inflight_request_then_bitexact_resume(tmp_path):
     """SIGTERM while a long-poll /result is parked: the reply completes
