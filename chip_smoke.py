@@ -5,30 +5,31 @@
     python chip_smoke.py --chips 4    # four chips: sharded + spanning only
 
 Runs the main path once, in this one process (which owns the chip),
-through the entry points a user calls, at the paper's sampling (m = 50
-samples x 5 passes, block 4096), and checks every result against the
-plain solver ``abo_minimize`` run on the same chip:
+through the entry points a user calls, and checks every result against
+the plain solver ``abo_minimize`` run on the same chip:
 
 - ``serve``: the HTTP front door (``repro.serve.Frontend`` over
   ``SolveService(SolveEngine(lanes=8))`` on 127.0.0.1, the code
-  ``solve_server --http`` runs) takes 12 jobs — griewank, sphere and
-  rastrigin at n in {1e5, 3e5, 1e6}, plus three repeats at new seeds —
-  over ``POST /submit``, and each ``/result`` long-poll is compared with
-  the reference's ``fun`` and the bytes of its ``x`` (bit-identity is
-  reported per job; ``ok`` also admits the stated tolerance of
-  ``ENGINE_FUN_ATOL_PER_COORD`` / ``ENGINE_MAX_DIFFER_FRAC``, see there);
-- ``big``: one griewank job at n = 1e8 through ``SolveEngine``, the same
-  check;
-- ``kernel``: the Pallas path, ``abo_minimize(..., use_kernel=True)`` at
-  n = 1e8, whose ``fun`` must agree with the jnp path within
-  ``KERNEL_FUN_ATOL`` (the kernel carries f32 aggregates through a pass
-  with no re-sync, so bit-identity is not its contract);
+  ``solve_server --http`` runs) takes 12 jobs over ``POST /submit`` —
+  griewank, sphere and rastrigin at n in {1e5, 3e5, 1e6} at the paper's
+  sampling, plus three jobs whose solution is off the grid's centre
+  column (see ``OFF_CENTRE``) — and each ``/result`` long-poll must equal
+  the reference bit for bit: ``fun``, the per-pass ``history`` and the
+  bytes of ``x`` (``engine/DESIGN.md`` § Bit-identity);
+- ``big``: one off-centre griewank job at n = 1e8 straight through
+  ``SolveEngine``, the same check;
+- ``kernel``: the Pallas path. ``abo_minimize(..., use_kernel=True)`` at
+  n = 1e8 and the paper's sampling, whose ``fun`` must agree with the jnp
+  path within ``KERNEL_FUN_ATOL`` (the kernel carries f32 aggregates
+  through a pass with no re-sync, so bit-identity is not its contract);
+  then every pass of an off-centre solve at n = 1e8, the kernel against
+  its jnp oracle ``kernels/coord_sweep/ref.py`` from the same input (see
+  ``KERNEL_*`` for what each pass must meet);
 - ``--chips 4``: ``SolveEngine(devices=4, span_pages=...)`` with one
-  griewank lane at n = 1e8 striped over the four chips and four whole
-  lanes at n = 1e6; every result is compared, as above, with
-  ``abo_minimize`` on one chip under the span config the engine derived,
-  and each chip's peak memory must hold at least half its quarter of the
-  striped lane's pages.
+  off-centre griewank lane at n = 1e8 striped over the four chips and four
+  whole lanes at n = 1e6; every result must equal ``abo_minimize`` on one
+  chip under the span config the engine derived, and each chip's peak
+  memory must hold at least half its quarter of the striped lane.
 
 Each phase prints one JSON line: device, wall seconds, the seconds and
 count of XLA compiles inside the phase (a persistent-cache hit builds
@@ -50,26 +51,33 @@ import time
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "src"))
 
 PAPER = {"samples_per_pass": 50, "n_passes": 5, "block_size": 4096}
-SERVE_JOBS = [(obj, n, seed)
-              for seed, (obj, n) in enumerate(
-                  [(o, n) for n in (100_000, 300_000, 1_000_000)
-                   for o in ("griewank", "sphere", "rastrigin")]
-                  + [("griewank", 100_000), ("sphere", 300_000),
-                     ("rastrigin", 1_000_000)])]
+# The benchmark domains are symmetric about their optimum 0, and the
+# paper's m = 50 puts 49 grid points on pass 0 with the centre one exactly
+# at 0: every coordinate lands on the optimum in pass 0, and passes 1-4
+# and the carried aggregates have nothing left to change. m = 51 (50 grid
+# points, none at the centre) leaves pass 0 one grid step away, so every
+# later pass, and the aggregate-coupled griewank selection, moves x.
+OFF_CENTRE = {**PAPER, "samples_per_pass": 51}
+SERVE_JOBS = [(o, n, seed, PAPER) for seed, (o, n) in enumerate(
+    (o, n) for n in (100_000, 300_000, 1_000_000)
+    for o in ("griewank", "sphere", "rastrigin"))] + [
+    ("griewank", 100_000, 9, OFF_CENTRE),
+    ("griewank", 1_000_000, 10, OFF_CENTRE),
+    ("shifted_sphere", 300_000, 11, PAPER)]   # optimum off every grid
 BIG_N = 100_000_000
+KERNEL_N = BIG_N
+# Kernel against the jnp path, both at paper sampling: both land every
+# coordinate on 0, so any fun above this is a page the kernel never swept
+# or a wrong number it wrote.
 KERNEL_FUN_ATOL = 1e-3
-# Engine vs abo_minimize. The engine's contract is bit-identity, and the
-# CPU tests hold it exactly; each line here reports it per job. On the
-# TPU the two programs reduce the aggregates over differently shaped
-# tiles (a batch of lanes against one dense vector), and XLA:TPU may
-# associate those sums differently: an ulp there can flip an argmin tie
-# and move a few coordinates by one grid step. So a job also passes when
-# its fun is within ENGINE_FUN_ATOL_PER_COORD * n of the reference and
-# at most ENGINE_MAX_DIFFER_FRAC of its coordinates differ; a wrong
-# result (a lost page or pass, stale aggregates) misses both by orders of
-# magnitude. Whether the TPU may differ at all is ROADMAP 3.2's decision.
-ENGINE_FUN_ATOL_PER_COORD = 1e-6
-ENGINE_MAX_DIFFER_FRAC = 0.01
+# Kernel against ref.py, per pass, from the same input. The two evaluate
+# cos/log with different instruction sequences (Mosaic vs XLA), and an
+# ulp there can flip a near-tie between two grid points: so at most this
+# share of coordinates may differ, none by more than one grid step, and
+# each aggregate (S, L, K) by at most KERNEL_AGG_RTOL of the magnitude it
+# entered the pass with (the carried sums round at that scale).
+KERNEL_X_DIFFER_FRAC = 1e-3
+KERNEL_AGG_RTOL = 1e-5
 # pages per device before a lane stripes: the 1e8 lane (24,415 pages)
 # splits into four shards, one per chip; a 1e6 lane (245 pages) stays whole
 SPAN_PAGES = 6104
@@ -115,25 +123,24 @@ def report(phase, devices, log, mark, t0, ok, **extra):
     return bool(ok)
 
 
-def compare(fun, x, ref) -> dict:
-    """An engine result against its ``abo_minimize`` reference: whether
-    the bits match, and if not, by how much and whether that is within
-    the stated tolerance (``passed``)."""
+def compare(fun, x, history, ref) -> dict:
+    """An engine result against its ``abo_minimize`` reference: the bits
+    of fun, of every pass's objective and of x must match. Where they do
+    not, by how much (recorded, not forgiven)."""
     import numpy as np
     x = np.asarray(x, np.float32)
     rx = np.asarray(ref.x, np.float32)
-    same = fun == float(ref.fun) and x.tobytes() == rx.tobytes()
-    out = {"bit_identical": bool(same), "passed": bool(same)}
+    hist = np.asarray(history, np.float32)
+    rhist = np.asarray(ref.history, np.float32)
+    same = (fun == float(ref.fun) and hist.tobytes() == rhist.tobytes()
+            and x.tobytes() == rx.tobytes())
+    out = {"bit_identical": bool(same)}
     if not same:
-        n = x.size
-        dfun = abs(fun - float(ref.fun))
-        differ = int(np.count_nonzero(x.view(np.uint32)
-                                      != rx.view(np.uint32)))
-        out.update(fun=fun, ref_fun=float(ref.fun), abs_dfun=dfun,
-                   coords_differ=differ,
-                   max_abs_dx=float(np.max(np.abs(x - rx))),
-                   passed=bool(dfun <= ENGINE_FUN_ATOL_PER_COORD * n
-                           and differ <= ENGINE_MAX_DIFFER_FRAC * n))
+        out.update(fun=fun, ref_fun=float(ref.fun),
+                   history=hist.tolist(), ref_history=rhist.tolist(),
+                   coords_differ=int(np.count_nonzero(
+                       x.view(np.uint32) != rx.view(np.uint32))),
+                   max_abs_dx=float(np.max(np.abs(x - rx))))
     return out
 
 
@@ -166,7 +173,7 @@ def _http(port, method, path, body=None, timeout=300):
         return resp.status, out, len(raw)
 
 
-def phase_serve(devices, log, jobs=SERVE_JOBS, config=PAPER):
+def phase_serve(devices, log):
     """The HTTP front door over an 8-lane engine, checked per job."""
     from repro.core.abo import ABOConfig
     from repro.engine import SolveEngine, SolveService
@@ -178,7 +185,7 @@ def phase_serve(devices, log, jobs=SERVE_JOBS, config=PAPER):
     server = threading.Thread(target=fe.serve, name="serve", daemon=True)
     server.start()
     ids = []
-    for obj, n, seed in jobs:
+    for obj, n, seed, config in SERVE_JOBS:
         st, out, _ = _http(port, "POST", "/submit", json.dumps(
             {"objective": obj, "n": n, "seed": seed, "config": config}))
         if st != 200:
@@ -202,77 +209,131 @@ def phase_serve(devices, log, jobs=SERVE_JOBS, config=PAPER):
     if fe.engine_error is not None:
         raise RuntimeError("engine failed") from fe.engine_error
     t_ref = time.perf_counter()
-    verdicts = [dict(objective=obj, n=n, **compare(
-        out["fun"], out["x"], reference(obj, n, ABOConfig(**config), seed)))
-        for (obj, n, seed), out in zip(jobs, results)]
-    matched = sum(v["bit_identical"] for v in verdicts)
+    verdicts = [dict(objective=obj, n=n, m=config["samples_per_pass"],
+                     **compare(out["fun"], out["x"], out["history"],
+                               reference(obj, n, ABOConfig(**config), seed)))
+                for (obj, n, seed, config), out in zip(SERVE_JOBS, results)]
     return report("serve", devices, log, mark, t0,
-                  all(v["passed"] for v in verdicts), serve_s=serve_s,
+                  all(v["bit_identical"] for v in verdicts), serve_s=serve_s,
                   ref_s=time.perf_counter() - t_ref, steps=steps,
-                  jobs=len(jobs), bit_identical=matched,
-                  result_reply_bytes=dict(zip(
-                      (f"{o}:{n}:{s}" for o, n, s in jobs), reply_bytes)),
+                  jobs=len(SERVE_JOBS),
+                  bit_identical=sum(v["bit_identical"] for v in verdicts),
+                  funs=[out["fun"] for out in results],
+                  result_reply_bytes=reply_bytes,
                   mismatches=[v for v in verdicts
                               if not v["bit_identical"]])
 
 
-def phase_big(devices, log, n=BIG_N, config=PAPER, seed=7):
+def phase_big(devices, log):
     """One paper-scale griewank job straight through SolveEngine."""
     from repro.core.abo import ABOConfig
     from repro.engine import JobSpec, SolveEngine
 
     mark, t0 = log.mark(), time.perf_counter()
-    cfg = ABOConfig(**config)
+    cfg, seed = ABOConfig(**OFF_CENTRE), 7
     eng = SolveEngine(lanes=8)
-    jid = eng.submit(JobSpec("griewank", n, cfg, seed=seed))
+    jid = eng.submit(JobSpec("griewank", BIG_N, cfg, seed=seed))
     eng.run()
     res = eng.result(jid)
     engine_s, steps = time.perf_counter() - t0, eng.step_count
     del eng
     t_ref = time.perf_counter()
-    verdict = compare(res.fun, res.x, reference("griewank", n, cfg, seed))
-    return report("big", devices, log, mark, t0, verdict["passed"],
-                  n=n, engine_s=engine_s,
+    verdict = compare(res.fun, res.x, res.history,
+                      reference("griewank", BIG_N, cfg, seed))
+    return report("big", devices, log, mark, t0, verdict["bit_identical"],
+                  n=BIG_N, engine_s=engine_s,
                   ref_s=time.perf_counter() - t_ref, steps=steps,
                   fun=res.fun, **verdict)
 
 
-def phase_kernel(devices, log, n=BIG_N, interpret=False):
-    """The Pallas sweep kernel against the jnp path, within a tolerance."""
+def kernel_vs_ref_passes(seed=5) -> list[dict]:
+    """Every pass of an off-centre griewank solve at ``KERNEL_N``: the
+    Pallas kernel and ref.py each sweep the same input, and the kernel's
+    output carries on to the next pass."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core.abo import ABOConfig, seeded_start
+    from repro.kernels.coord_sweep.kernel import LANES
+    from repro.kernels.coord_sweep.ops import pack_aggs, sweep_pass
+    from repro.kernels.coord_sweep.ref import sweep_pass_ref
+    from repro.objectives import GRIEWANK
+
+    m, bsz, passes = (OFF_CENTRE[k] for k in
+                      ("samples_per_pass", "block_size", "n_passes"))
+    lo, hi = GRIEWANK.lower, GRIEWANK.upper
+    ref = jax.jit(sweep_pass_ref, static_argnames=(
+        "m", "n_valid", "lower", "upper", "half_width", "lam", "is_first"))
+    n_pad = -(-KERNEL_N // bsz) * bsz
+    # padding holds 0, as in abo_minimize(use_kernel=True)
+    x = seeded_start(seed, n_pad, jnp.float32, lo, hi).at[KERNEL_N:].set(0)
+    aggs = pack_aggs(GRIEWANK.aggregates(x, KERNEL_N, agg_dtype=jnp.float32))
+    shrink = ABOConfig(**OFF_CENTRE).resolved_shrink()
+    out = []
+    for p in range(passes):
+        # abo_minimize_kernel's schedule
+        hw = 0.5 * (hi - lo) * shrink ** p
+        kw = dict(m=m, n_valid=KERNEL_N, half_width=float(hw),
+                  lam=p / (passes - 1), is_first=p == 0)
+        xk, ak = sweep_pass(x.reshape(-1, LANES), aggs, block=bsz, **kw)
+        xr, ar = ref(x.reshape(-1, bsz), aggs, lower=lo, upper=hi, **kw)
+        xk_h = np.asarray(xk).reshape(-1)[:KERNEL_N]
+        xr_h = np.asarray(xr).reshape(-1)[:KERNEL_N]
+        a_in, ak3, ar3 = (np.asarray(a[0, :3], np.float64)
+                          for a in (aggs, ak, ar))
+        step = (hi - lo if p == 0 else 2 * hw) / (m - 2)
+        differ = int(np.count_nonzero(xk_h != xr_h))
+        max_dx = float(np.max(np.abs(xk_h - xr_h)))
+        agg_err = float(np.max(np.abs(ak3 - ar3) / np.maximum(
+            np.abs(a_in), 1.0)))
+        out.append(dict(
+            pass_idx=p, coords_differ=differ, max_abs_dx=max_dx,
+            grid_step=step, agg_rel_err=agg_err, aggs=ak3.tolist(),
+            ref_aggs=ar3.tolist(),
+            passed=bool(differ <= KERNEL_X_DIFFER_FRAC * KERNEL_N
+                        and max_dx <= step * (1 + 1e-3)
+                        and agg_err <= KERNEL_AGG_RTOL)))
+        x, aggs = xk.reshape(-1), ak
+    return out
+
+
+def phase_kernel(devices, log):
+    """The Pallas sweep kernel against the jnp path and against its
+    oracle, on the chip."""
     import numpy as np
     from repro.core.abo import ABOConfig, abo_minimize
-    from repro.kernels.coord_sweep.ops import abo_minimize_kernel
     from repro.objectives import GRIEWANK
 
     mark, t0 = log.mark(), time.perf_counter()
-    cfg = ABOConfig(**PAPER)
-    if interpret:              # CPU rehearsal: the Pallas interpreter
-        kern = abo_minimize_kernel(n, config=cfg, interpret=True)
-    else:
-        kern = abo_minimize(GRIEWANK, n, config=ABOConfig(
-            **PAPER, use_kernel=True))
+    kern = abo_minimize(GRIEWANK, KERNEL_N, config=ABOConfig(
+        **PAPER, use_kernel=True))
     kernel_s = time.perf_counter() - t0
-    jnp_path = abo_minimize(GRIEWANK, n, config=cfg)
+    jnp_path = abo_minimize(GRIEWANK, KERNEL_N, config=ABOConfig(**PAPER))
     dfun = abs(kern.fun - jnp_path.fun)
     dx = float(np.max(np.abs(np.asarray(kern.x) - np.asarray(jnp_path.x))))
+    t_passes = time.perf_counter()
+    passes = kernel_vs_ref_passes()
     return report("kernel", devices, log, mark, t0,
-                  dfun <= KERNEL_FUN_ATOL, n=n, kernel_s=kernel_s,
+                  dfun <= KERNEL_FUN_ATOL and all(
+                      p["passed"] for p in passes),
+                  n=KERNEL_N, kernel_s=kernel_s,
                   fun=kern.fun, jnp_fun=jnp_path.fun, abs_dfun=dfun,
-                  fun_atol=KERNEL_FUN_ATOL, max_abs_dx=dx)
+                  fun_atol=KERNEL_FUN_ATOL, max_abs_dx=dx,
+                  vs_ref_s=time.perf_counter() - t_passes,
+                  vs_ref_passes=passes)
 
 
-def phase_four_chips(devices, log, big_n=BIG_N, whole_n=1_000_000,
-                     span_pages=SPAN_PAGES, config=PAPER):
+def phase_four_chips(devices, log):
     """Sharded pools + one spanning lane over four chips, each job checked
     against abo_minimize on one chip."""
     from repro.core.abo import ABOConfig
     from repro.engine import JobSpec, SolveEngine
 
     mark, t0 = log.mark(), time.perf_counter()
-    cfg = ABOConfig(**config)
-    eng = SolveEngine(lanes=8, devices=4, span_pages=span_pages)
-    specs = [JobSpec("griewank", big_n, cfg, seed=11)] + [
-        JobSpec("griewank", whole_n, cfg, seed=20 + i) for i in range(4)]
+    cfg = ABOConfig(**OFF_CENTRE)
+    eng = SolveEngine(lanes=8, devices=4, span_pages=SPAN_PAGES)
+    specs = [JobSpec("griewank", BIG_N, cfg, seed=11)] + [
+        JobSpec("griewank", 1_000_000, cfg, seed=20 + i) for i in range(4)]
     ids = [eng.submit(s) for s in specs]
     eng.run()
     # nothing but the engine has touched the chips yet: each one's peak is
@@ -284,12 +345,13 @@ def phase_four_chips(devices, log, big_n=BIG_N, whole_n=1_000_000,
     engine_s, steps = time.perf_counter() - t0, eng.step_count
     del eng
     t_ref = time.perf_counter()
-    verdicts = [compare(r.fun, r.x, reference("griewank", s.n, c, s.seed))
+    verdicts = [compare(r.fun, r.x, r.history,
+                        reference("griewank", s.n, c, s.seed))
                 for s, c, r in zip(specs, derived, results)]
-    share = big_n * 4 // 4                    # f32 coords per chip
+    share = BIG_N * 4 // 4                    # f32 bytes per chip
     spread = all(p is not None and p >= share // 2 for p in peaks)
     return report("four_chips", devices, log, mark, t0,
-                  all(v["passed"] for v in verdicts) and spread,
+                  all(v["bit_identical"] for v in verdicts) and spread,
                   engine_s=engine_s,
                   ref_s=time.perf_counter() - t_ref, steps=steps,
                   span_coords=derived[0].span_coords,

@@ -165,6 +165,10 @@ def _block_step(obj, cfg, probe_tile, xb, aggs, idx, valid, half_width,
     x_sel = jnp.take_along_axis(cands, sel[:, None], axis=1)[:, 0]
     d_sel = jnp.take_along_axis(
         delta, sel[:, None, None], axis=1)[:, 0, :]        # (B, A)
+    # a frozen padding coordinate's delta is t(x) - t(x), which is not 0
+    # once the compiler contracts it into an FMA (the rounding error of
+    # t(x)): zero it, or padding noise decides the guarded commit
+    d_sel = jnp.where(valid[:, None], d_sel, jnp.zeros_like(d_sel))
     # tree_sum, not d_sel.sum(0): the commit reduction must round the
     # same way in the dense scan and the engine's vmapped sweep
     aggs_new = aggs + tree_sum(d_sel).astype(agg_dt)

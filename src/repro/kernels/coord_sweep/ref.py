@@ -1,18 +1,37 @@
 """Pure-jnp oracle for the coord_sweep kernel — identical semantics.
 
 Gauss-Seidel across blocks (lax.scan), Jacobi within a block, guarded
-commits, incumbent candidate column, frozen padding — bit-for-bit the same
-algorithm as kernel.py, expressed with plain jnp so interpret-mode kernel
-runs can be asserted allclose against it.
+commits, incumbent candidate column, frozen padding — the same algorithm
+as kernel.py, expressed with plain jnp so interpret-mode kernel runs can
+be checked against it. The grid (a float64 linspace rounded once) and the
+block-commit sums are built here on their own, not from the kernel's
+helpers; the sums follow the association the kernel documents — halve
+the ``(block / LANES, LANES)`` tile's rows, then its lanes, an odd last
+row carried along — because the aggregates of a pass that collapses onto
+the optimum are all cancellation, and only the same association gives
+the same bits.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.kernels.coord_sweep.kernel import (LANES, _combine,
-                                              _griewank_planes, grid_deltas,
-                                              tile_sum)
+from repro.kernels.coord_sweep.kernel import LANES, _combine, _griewank_planes
+
+
+def _halving_sum(a):
+    """Sum over axis 0: add the second half onto the first (an odd last
+    entry carried to the next round), until one entry is left."""
+    while a.shape[0] > 1:
+        h = a.shape[0] // 2
+        a = jnp.concatenate([a[:h] + a[h:2 * h], a[2 * h:]])
+    return a[0]
+
+
+def _block_sum(d):
+    """(block,) -> scalar: the tile's rows halved, then its lanes."""
+    return _halving_sum(_halving_sum(d.reshape(-1, LANES)))
 
 
 def sweep_pass_ref(
@@ -30,6 +49,8 @@ def sweep_pass_ref(
     n_blocks, block = x2d.shape
     dt = x2d.dtype
     s0, l0, k0 = aggs[0, 0], aggs[0, 1], aggs[0, 2]
+    hw = 0.5 * (upper - lower) if is_first else half_width
+    offs = jnp.asarray(np.linspace(-hw, hw, m - 1).astype(dt))   # (m-1,)
 
     def body(carry, blk):
         x2d, s0, l0, k0 = carry
@@ -37,14 +58,9 @@ def sweep_pass_ref(
         jlane = jnp.broadcast_to(jnp.arange(m)[None, :], (block, m))
         bidx = blk * block + jnp.broadcast_to(jnp.arange(block)[:, None], (block, m))
 
-        if is_first:
-            center = jnp.full((block,), 0.5 * (lower + upper), dt)
-            hw = 0.5 * (upper - lower)
-        else:
-            center = xb
-            hw = half_width
-        grid = jnp.clip(center[:, None] + grid_deltas(hw, m, dt)[None, :],
-                        lower, upper)
+        center = (jnp.full((block,), 0.5 * (lower + upper), dt) if is_first
+                  else xb)
+        grid = jnp.clip(center[:, None] + offs[None, :], lower, upper)
         cands = jnp.concatenate([grid, xb[:, None]], axis=1)
         valid = bidx < n_valid
         cands = jnp.where(valid, cands, xb[:, None])
@@ -59,10 +75,9 @@ def sweep_pass_ref(
         sel = jnp.argmin(f, axis=1)
         onehot = (jlane == sel[:, None]).astype(dt)
         x_sel = jnp.sum(cands * onehot, axis=1)
-        # the kernel's commit reduction: selected deltas in the
-        # lane-dense (block / LANES, LANES) tile, summed rows then lanes
-        s1, l1, k1 = (a0 + tile_sum(jnp.sum(d * onehot, axis=1).reshape(
-            -1, LANES))[0, 0] for a0, d in ((s0, ds), (l0, dl), (k0, dk)))
+        s1 = s0 + _block_sum(jnp.sum(ds * onehot, axis=1))
+        l1 = l0 + _block_sum(jnp.sum(dl * onehot, axis=1))
+        k1 = k0 + _block_sum(jnp.sum(dk * onehot, axis=1))
         accept = _combine(s1, l1, k1, lam) <= _combine(s0, l0, k0, lam)
 
         x2d = x2d.at[blk].set(jnp.where(accept, x_sel, xb))

@@ -330,8 +330,8 @@ class Router:
               timeout: float | None = None):
         """Forward one request; (status, payload_bytes, retry_after).
 
-        Any transport failure — refused, reset, timed out, worker mid-
-        restart — is one deliberate answer: 503 ``worker_unavailable``
+        Any transport failure — refused, reset, timed out, a reply cut
+        short, worker mid-restart — is one deliberate answer: 503 ``worker_unavailable``
         with a Retry-After from observed restart times."""
         import http.client
         port = w.port
@@ -351,7 +351,7 @@ class Router:
                 return resp.status, data, resp.getheader("Retry-After")
             finally:
                 conn.close()
-        except OSError:
+        except (OSError, http.client.HTTPException):
             self._c_proxy_err("router_proxy_errors_total",
                               "proxied requests that failed in "
                               "transport", worker=w.name).inc()

@@ -72,6 +72,27 @@ def test_mixed_n_shares_family_pool():
         assert abs(eng.result(jid).fun - _solo_fun(spec)) < 1e-5
 
 
+@pytest.mark.parametrize("obj,n,m", [("sphere", 4000, 51),
+                                     ("shifted_sphere", 30000, 50)])
+def test_padded_lane_bit_identical_off_grid(obj, n, m):
+    """A lane whose last block is part padding, on a trajectory that stays
+    off the grid's centre: abo_minimize pads with its seeded start, the
+    engine with zeros, and a padding coordinate's t(x) - t(x) (not 0 once
+    contracted into an FMA) must not decide the guarded commit — fun, the
+    per-pass history and x equal bit for bit."""
+    cfg = ABOConfig(samples_per_pass=m, n_passes=5, block_size=4096)
+    spec = JobSpec(obj, n, cfg, seed=11)
+    eng = SolveEngine(lanes=2)
+    jid = eng.submit(spec)
+    eng.run()
+    got = eng.result(jid)
+    ref = abo_minimize(OBJECTIVES[obj], n, config=cfg, seed=11)
+    assert got.fun == ref.fun
+    np.testing.assert_array_equal(np.asarray(got.history, np.float32),
+                                  np.asarray(ref.history))
+    np.testing.assert_array_equal(np.asarray(got.x), np.asarray(ref.x))
+
+
 def test_submit_poll_cancel_lifecycle():
     # max_fuse=1: strict pass-per-step, so a job is observably RUNNING
     eng = SolveEngine(lanes=1, max_fuse=1)

@@ -22,7 +22,8 @@ from repro.objectives import GRIEWANK, griewank
 # coord_sweep
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("n_blocks,block,m", [(1, 128, 16), (4, 256, 64),
-                                              (3, 512, 128), (2, 128, 33)])
+                                              (3, 512, 128), (2, 128, 33),
+                                              (2, 384, 24)])
 @pytest.mark.parametrize("lam,is_first", [(0.0, True), (0.5, False),
                                           (1.0, False)])
 def test_coord_sweep_vs_ref(n_blocks, block, m, lam, is_first, rng):
