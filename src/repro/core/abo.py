@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.objectives.base import SeparableObjective
+from repro.objectives.base import SeparableObjective, tree_sum
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,26 +121,6 @@ def _candidate_grid(xb, lo, hi, half_width, m, is_first_pass):
     offs = jnp.linspace(-1.0, 1.0, m - 1, dtype=dt)          # (m-1,)
     grid = jnp.clip(center + w * offs[None, :], lo, hi)
     return jnp.concatenate([grid, xb[:, None]], axis=1)       # (B, m)
-
-
-def tree_sum(x: jnp.ndarray) -> jnp.ndarray:
-    """Sum over axis 0 with an EXPLICIT balanced association tree.
-
-    ``x.sum(axis=0)`` leaves the accumulation order to the backend, and
-    XLA:CPU picks per-compilation strategies — the same logical sum can
-    round differently between the dense solver's scan program and the
-    engine's vmapped row-sweep program, or between physical lengths. Here
-    the tree is spelled out as elementwise adds (halve, add, repeat; an
-    odd leftover rides along unmodified), which the compiler cannot
-    reassociate, so any two programs summing the same values get the same
-    bits. Cost is the same ~len(x) adds a native reduce performs.
-    """
-    while x.shape[0] > 1:
-        k = x.shape[0] // 2
-        head = x[:k] + x[k: 2 * k]
-        x = head if x.shape[0] == 2 * k else \
-            jnp.concatenate([head, x[2 * k:]], axis=0)
-    return x[0]
 
 
 def _block_step(obj, cfg, probe_tile, xb, aggs, idx, valid, half_width,

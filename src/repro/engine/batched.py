@@ -231,6 +231,24 @@ def zeros_pool_state(obj: SeparableObjective, key: tuple, lanes: int,
     return state
 
 
+def write_pages(pool, pages, rows):
+    """``pool[pages[i]] = rows[i]`` for each page entry, in order.
+
+    Placement tables pad with the scratch page, so ``pages`` repeats it.
+    XLA leaves the order of a scatter's duplicate updates to the backend,
+    and on a TPU v5e a scatter of one lane's 256 page rows (245 real, 11
+    scratch) wrote the scratch rows' zeros over real pages 224-245. One
+    ``dynamic_update_slice`` per row means the same on every backend."""
+    pages = pages.reshape(-1)
+    rows = rows.reshape(pages.shape[0], pool.shape[1]).astype(pool.dtype)
+
+    def body(i, pool):
+        return jax.lax.dynamic_update_slice(pool, rows[i][None],
+                                            (pages[i], 0))
+
+    return jax.lax.fori_loop(0, pages.shape[0], body, pool)
+
+
 def resize_pool_state(state: PoolState, lanes: int, pages: int,
                       mesh: Mesh | None = None) -> PoolState:
     """Re-shape a pool's device state to ``lanes`` slots and ``pages``
@@ -798,7 +816,7 @@ class PoolOps:
 
             vals = jax.vmap(write_one)(gpage_tbl)     # (gl, block)
             st = dataclasses.replace(
-                state, pool=state.pool.at[pg_tbl].set(vals))
+                state, pool=write_pages(state.pool, pg_tbl, vals))
             st = dataclasses.replace(
                 st,
                 hist=st.hist.at[lane].set(
@@ -828,12 +846,10 @@ class PoolOps:
         return fn
 
     def _write_lanes(self, state, lanes, pages, xrow, aggs, n_valid):
-        v, g = pages.shape
-        bsz = self.cfg.block_size
+        v = pages.shape[0]
         return dataclasses.replace(
             state,
-            pool=state.pool.at[pages].set(
-                xrow.reshape(v, g, bsz).astype(state.pool.dtype)),
+            pool=write_pages(state.pool, pages, xrow),
             aggs=state.aggs.at[lanes].set(aggs.astype(state.aggs.dtype)),
             hist=state.hist.at[lanes].set(
                 jnp.zeros((v, self.cfg.n_passes), state.hist.dtype)),

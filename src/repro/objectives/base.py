@@ -31,6 +31,28 @@ def _default_agg_dtype() -> jnp.dtype:
     return jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
 
 
+def tree_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum over axis 0 with an EXPLICIT balanced association tree.
+
+    ``x.sum(axis=0)`` leaves the accumulation order to the backend, which
+    picks it per program: XLA:CPU varies it with the physical length and
+    the surrounding program, and XLA:TPU lays a vmapped ``(v, tile,
+    n_aggs)`` batch out differently from one ``(tile, n_aggs)`` tile. So
+    the same logical sum can round differently in the dense solver's scan
+    and the engine's vmapped or sharded programs. Here the tree is spelled
+    out as elementwise adds (halve, add, repeat; an odd leftover rides
+    along unmodified), which the compiler cannot reassociate, so any two
+    programs summing the same values get the same bits. Cost is the same
+    ~len(x) adds a native reduce performs.
+    """
+    while x.shape[0] > 1:
+        k = x.shape[0] // 2
+        head = x[:k] + x[k: 2 * k]
+        x = head if x.shape[0] == 2 * k else \
+            jnp.concatenate([head, x[2 * k:]], axis=0)
+    return x[0]
+
+
 @dataclasses.dataclass(frozen=True)
 class SeparableObjective:
     """A sum-decomposable objective with O(1) incremental probes.
@@ -70,6 +92,8 @@ class SeparableObjective:
     # vector changes low bits), so this invariance cannot be left to the
     # backend; the engine's bit-identity contract (gathered lane views at
     # ladder-padded widths == the dense solver's padded vector) rests on it.
+    # For the same reason each tile sums through :func:`tree_sum`, not a
+    # native reduce, whose order the backend may choose per layout.
     REDUCE_TILE = 4096
 
     def aggregates(
@@ -97,7 +121,7 @@ class SeparableObjective:
             idx = start + jnp.arange(tile)
             t = self.terms(idx, xc).astype(agg_dtype)
             mask = (idx < n_valid)[:, None].astype(agg_dtype)
-            return (t * mask).sum(axis=0)
+            return tree_sum(t * mask)
 
         n_full, tail = divmod(n, tile)
         acc = jnp.zeros((self.n_aggs,), agg_dtype)
@@ -132,7 +156,7 @@ class SeparableObjective:
         idx = tile_idx * tile + jnp.arange(tile)
         t = self.terms(idx, xc).astype(agg_dtype)
         mask = (idx < n_valid)[:, None].astype(agg_dtype)
-        return (t * mask).sum(axis=0)
+        return tree_sum(t * mask)
 
     def fold_tile_partials(self, partials, n_tiles, *, agg_dtype=None):
         """Left-fold fixed-origin tile partials in index order.

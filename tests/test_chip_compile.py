@@ -11,6 +11,7 @@ a time, and every test worker imports this file.
 """
 import dataclasses
 import os
+import re
 
 import numpy as np
 import pytest
@@ -19,7 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
-from repro.core.abo import ABOConfig
+from repro.core.abo import (ABOConfig, _abo_jit, _default_probe_tile,
+                            effective_config)
 from repro.engine import batched, scheduler
 from repro.engine.scheduler import LanePool
 from repro.kernels.coord_sweep.kernel import AGG_LANES, LANES
@@ -82,20 +84,22 @@ def test_griewank_kernel_compiles_1e8(one_chip):
 
 
 def _one_lane_pool(n: int, cfg: ABOConfig, mesh=None, n_dev=1,
-                   span_pages=None) -> LanePool:
-    """A host-side LanePool holding one griewank lane of ``n`` coords —
-    whole, or striped in shards of ``span_pages`` over the mesh."""
+                   span_pages=None, lanes=1) -> LanePool:
+    """A host-side LanePool holding ``lanes`` griewank lanes of ``n``
+    coords — whole, or striped in shards of ``span_pages`` over the
+    mesh."""
     key = batched.family_key("griewank", n, cfg)
     pool = LanePool(key=key, obj=GRIEWANK, lanes=8, mesh=mesh, n_dev=n_dev)
-    slot = pool.take_slot()
-    pool.job_ids[slot] = "big"
     pages = batched.pages_for(n, cfg.block_size)
-    if span_pages is None:
-        pool.lane_dev[slot] = 0
-        pool.page_table[slot] = pool.alloc_pages(pages, 0)
-    else:
-        pool.page_table[slot], pool.lane_dev[slot] = pool.alloc_span_pages(
-            pages, span_pages)
+    for j in range(lanes):
+        slot = pool.take_slot()
+        pool.job_ids[slot] = f"job{j}"
+        if span_pages is None:
+            pool.lane_dev[slot] = 0
+            pool.page_table[slot] = pool.alloc_pages(pages, 0)
+        else:
+            pool.page_table[slot], pool.lane_dev[slot] = \
+                pool.alloc_span_pages(pages, span_pages)
     return pool
 
 
@@ -122,6 +126,63 @@ def test_fused_step_1e9_fits_one_chip(one_chip):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+def _float_sums(hlo: str) -> list[str]:
+    """The reduce instructions of an optimized HLO text that add floats
+    over a dimension longer than one. An argmin's (value, index) reduce
+    has a tuple result and is not matched; the compiler's reduces over a
+    size-1 dimension are layout changes that add nothing."""
+    shapes = dict(re.findall(r"(%[\w.\-]+) = \w+\[([\d,]*)\]", hlo))
+    found = []
+    for m in re.finditer(r"(%[\w.\-]+) = f\d+\[[\d,]*\]\S* reduce\("
+                         r"(%[\w.\-]+), [^)]*\), dimensions=\{([\d,]*)\}",
+                         hlo):
+        dims = [int(d) for d in shapes[m.group(2)].split(",") if d]
+        if any(dims[int(a)] > 1 for a in m.group(3).split(",")):
+            found.append(m.group(0))
+    return found
+
+
+def test_main_path_sums_in_fixed_order(one_chip):
+    """The dense solver and the engine's fused step over three lanes sum
+    floats only through explicit add trees. A native reduce leaves the
+    association to the TPU compiler, which lays the engine's vmapped
+    ``(v, 4096, 3)`` tile reduce out differently from the dense solver's
+    ``(4096, 3)`` one, so engine and ``abo_minimize`` can round apart on
+    the chip."""
+    n = 10_000
+    cfg = effective_config(ABOConfig(samples_per_pass=51), n)
+    n_pad = -(-n // cfg.block_size) * cfg.block_size
+    dense = _abo_jit.lower(
+        jax.ShapeDtypeStruct((n_pad,), jnp.float32, sharding=one_chip),
+        GRIEWANK, n, cfg, _default_probe_tile(GRIEWANK)).compile()
+    pool = _one_lane_pool(n, cfg, lanes=3)
+    fused = _compile_fused(pool, batched.PoolState(*(one_chip,) * 5),
+                           lambda a: _shape(a, one_chip))
+    assert _float_sums(dense.as_text()) == []
+    assert _float_sums(fused.as_text()) == []
+
+
+def test_placement_writes_pages_without_scatter(one_chip):
+    """Placing one n = 1e6 lane writes its 256 rung-padded page rows (245
+    real, 11 on the scratch page) one dynamic_update_slice at a time. As
+    one scatter with those duplicate indices, the v5e wrote the scratch
+    rows' zeros over real pages 224-245."""
+    key = batched.family_key("griewank", 10 ** 6, PAPER)
+    ops = batched.PoolOps(GRIEWANK, key, 8, 256, None)
+    state = jax.eval_shape(lambda: batched.zeros_pool_state(
+        GRIEWANK, key, 8, 256))
+    state = jax.tree_util.tree_map(lambda a: _shape(a, one_chip), state)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = ops.place(256, 1).lower(
+        state, arg((1,), jnp.int32), arg((1, 256), jnp.int32),
+        arg((1,), jnp.bool_), arg((1,), jnp.uint32),
+        arg((1,), jnp.int32)).compile().as_text()
+    assert not re.search(r"= f32\[256,4096\]\S* scatter\(", text)
+
+
 def test_spanning_fused_step_d4_compiles(topo, monkeypatch):
     """The D = 4 spanning fused step: one lane striped over a v5e:2x2 mesh,
     with the per-pass cross-chip all-reduce in the compiled program."""
@@ -136,4 +197,5 @@ def test_spanning_fused_step_d4_compiles(topo, monkeypatch):
                               lambda a: a)
     text = compiled.as_text()
     assert "all-reduce" in text
+    assert _float_sums(text) == []
     assert _device_bytes(compiled) < HBM_BYTES
