@@ -291,7 +291,7 @@ def resize_pool_state(state: PoolState, lanes: int, pages: int,
         fn = _RESIZE_CACHE.get(ck)
         if fn is None:
 
-            def local_resize(pool, aggs, hist, pass_idx, n_valid):
+            def resize_sharded(pool, aggs, hist, pass_idx, n_valid):
                 if loc_new > loc_old:
                     pool = jnp.zeros((loc_new, pool.shape[1]),
                                      pool.dtype).at[:loc_old].set(pool)
@@ -304,7 +304,7 @@ def resize_pool_state(state: PoolState, lanes: int, pages: int,
                 return pool, aggs, hist, pass_idx, n_valid
 
             fn = jax.jit(jax.shard_map(
-                local_resize, mesh=mesh, check_vma=False,
+                resize_sharded, mesh=mesh, check_vma=False,
                 in_specs=(P("pool", None), P(), P(), P(), P()),
                 out_specs=(P("pool", None), P(), P(), P(), P())),
                 donate_argnums=(0, 1, 2, 3, 4))
@@ -372,6 +372,13 @@ class PoolOps:
 
     All state arguments are donated: the scheduler threads one PoolState
     through, so buffers update in place.
+
+    Each executable's HLO module is named for what it does (the profiler
+    shows ``jit_<name>(<hash>)``): ``fused_step``, ``place``, ``place_x``,
+    ``finalize``, with a ``_sharded`` suffix on a mesh, and the mesh-only
+    ``place_span`` and ``finalize_span``; pool resizes are
+    ``host_resize`` / ``resize_sharded``. A trace reduction finds the
+    fused step's device time by that name.
 
     With a ``mesh`` the same methods return shard_map'd executables over
     *per-device* tables (leading device axis, local page ids) plus an
@@ -592,7 +599,7 @@ class PoolOps:
         if self.mesh is None:
             assert span is None, "striped spanning lanes need a mesh"
 
-            def run(state: PoolState, n_fused, shard_rows, *arrs):
+            def fused_step(state: PoolState, n_fused, shard_rows, *arrs):
                 band_args = [arrs[4 * i: 4 * i + 4] for i in range(n_bands)]
                 sync_args = arrs[4 * n_bands: 4 * n_bands + 2]
 
@@ -604,11 +611,11 @@ class PoolOps:
 
                 return jax.lax.fori_loop(0, n_fused, one_pass, state)
 
-            fn = jax.jit(run, donate_argnums=(0,))
+            fn = jax.jit(fused_step, donate_argnums=(0,))
         else:
 
-            def run_local(state: PoolState, n_fused, owner, shard_rows,
-                          *arrs):
+            def fused_step_sharded(state: PoolState, n_fused, owner,
+                                   shard_rows, *arrs):
                 my = axis_linear_index(("pool",))
                 band_args = [tuple(a[0] for a in arrs[4 * i: 4 * i + 3])
                              + (arrs[4 * i + 3][0],) for i in range(n_bands)]
@@ -648,7 +655,7 @@ class PoolOps:
                 P(), P(), P("pool", None), P("pool", None),
                 P("pool", None, None), P("pool", None))
             fn = jax.jit(jax.shard_map(
-                run_local, mesh=self.mesh, check_vma=False,
+                fused_step_sharded, mesh=self.mesh, check_vma=False,
                 in_specs=(_state_specs(), P(), P(), P())
                 + band_specs * n_bands
                 + (P("pool", None), P("pool", None, None))
@@ -686,20 +693,20 @@ class PoolOps:
 
         if self.mesh is None:
 
-            def run(state: PoolState, lanes, pages, seeded, seeds, n_valid):
+            def place(state: PoolState, lanes, pages, seeded, seeds, n_valid):
                 xr, ag = jax.vmap(init_row)(seeds, seeded, n_valid)
                 return self._write_lanes(state, lanes, pages, xr, ag,
                                          n_valid)
 
-            fn = jax.jit(run, donate_argnums=(0,))
+            fn = jax.jit(place, donate_argnums=(0,))
         else:
             # sharded: per-device tables; every device computes the whole
             # v-batch of start rows (v is a refill batch, tiny next to a
             # sweep) but only ITS lanes' rows are real — the rest target
             # its local scratch slot/page and the owner psum restores one
             # authoritative value per slot across replicas
-            def run_local(state: PoolState, owner, lanes, pages, seeded,
-                          seeds, n_valid):
+            def place_sharded(state: PoolState, owner, lanes, pages,
+                              seeded, seeds, n_valid):
                 my = axis_linear_index(("pool",))
                 lanes, pages = lanes[0], pages[0]
                 seeded, seeds, n_valid = seeded[0], seeds[0], n_valid[0]
@@ -708,7 +715,7 @@ class PoolOps:
                 return self._reconcile_slots(st, owner, my)
 
             fn = jax.jit(jax.shard_map(
-                run_local, mesh=self.mesh, check_vma=False,
+                place_sharded, mesh=self.mesh, check_vma=False,
                 in_specs=(_state_specs(), P(), P("pool", None),
                           P("pool", None, None), P("pool", None),
                           P("pool", None), P("pool", None)),
@@ -737,17 +744,17 @@ class PoolOps:
         obj = self.obj
         if self.mesh is None:
 
-            def run(state: PoolState, lane, pages, xrow, n_valid):
+            def place_x(state: PoolState, lane, pages, xrow, n_valid):
                 ag = obj.aggregates(xrow, n_valid)
                 return self._write_lanes(
                     state, lane[None], pages[None], xrow[None], ag[None],
                     n_valid[None])
 
-            fn = jax.jit(run, donate_argnums=(0,))
+            fn = jax.jit(place_x, donate_argnums=(0,))
         else:
 
-            def run_local(state: PoolState, owner, lane, pages, xrow,
-                          n_valid):
+            def place_x_sharded(state: PoolState, owner, lane, pages,
+                                xrow, n_valid):
                 my = axis_linear_index(("pool",))
                 lane, pages, xrow, n_valid = (lane[0], pages[0], xrow[0],
                                               n_valid[0])
@@ -758,7 +765,7 @@ class PoolOps:
                 return self._reconcile_slots(st, owner, my)
 
             fn = jax.jit(jax.shard_map(
-                run_local, mesh=self.mesh, check_vma=False,
+                place_x_sharded, mesh=self.mesh, check_vma=False,
                 in_specs=(_state_specs(), P(), P("pool"),
                           P("pool", None), P("pool", None), P("pool")),
                 out_specs=_state_specs()), donate_argnums=(0,))
@@ -792,9 +799,9 @@ class PoolOps:
         obj, cfg, dt = self.obj, self.cfg, self.dtype
         bsz = cfg.block_size
 
-        def run_local(state: PoolState, lane, n_valid, seed, seeded,
-                      poison, n_tiles, pg_tbl, gpage_tbl, tile_idx,
-                      tile_pages, tile_off):
+        def place_span(state: PoolState, lane, n_valid, seed, seeded,
+                       poison, n_tiles, pg_tbl, gpage_tbl, tile_idx,
+                       tile_pages, tile_off):
             lane, n_valid = lane[0], n_valid[0]
             seed, seeded, poison = seed[0], seeded[0], poison[0]
             n_tiles = n_tiles[0]
@@ -836,7 +843,7 @@ class PoolOps:
                 st, aggs=st.aggs.at[lane].set(ag[0].astype(st.aggs.dtype)))
 
         fn = jax.jit(jax.shard_map(
-            run_local, mesh=self.mesh, check_vma=False,
+            place_span, mesh=self.mesh, check_vma=False,
             in_specs=(_state_specs(), P(), P(), P(), P(), P(), P(),
                       P("pool", None), P("pool", None),
                       P("pool", None), P("pool", None, None),
@@ -874,7 +881,7 @@ class PoolOps:
         obj = self.obj
         if self.mesh is None:
 
-            def run(state: PoolState, lanes, pages):
+            def finalize(state: PoolState, lanes, pages):
                 xrow = self._gather_rows(state, pages)
                 nv = state.n_valid[lanes]
                 f = jax.vmap(lambda xr, n: obj.combine(obj.aggregates(
@@ -883,14 +890,14 @@ class PoolOps:
 
             # repro: allow[RPR005] finalize reads pool state the next step
             # still owns — donating would free live pages; no static args
-            fn = jax.jit(run)
+            fn = jax.jit(finalize)
         else:
             # sharded: finisher i's row in each output is computed by its
             # resident device (row_dev[i]) from its local pages; the other
             # devices produce scratch garbage in that row, which the
             # owner-selected psum discards — outputs land replicated, so
             # the host reads exact per-lane values once
-            def run_local(state: PoolState, row_dev, lanes, pages):
+            def finalize_sharded(state: PoolState, row_dev, lanes, pages):
                 my = axis_linear_index(("pool",))
                 lanes, pages = lanes[0], pages[0]
                 xrow = self._gather_rows(state, pages)
@@ -905,7 +912,7 @@ class PoolOps:
             # repro: allow[RPR005] sharded finalize: same read-only contract
             # as the unsharded branch — state must stay live for stepping
             fn = jax.jit(jax.shard_map(
-                run_local, mesh=self.mesh, check_vma=False,
+                finalize_sharded, mesh=self.mesh, check_vma=False,
                 in_specs=(_state_specs(), P(), P("pool", None),
                           P("pool", None, None)),
                 out_specs=(P(), P(), P())))
@@ -931,7 +938,7 @@ class PoolOps:
         assert self.mesh is not None, "finalize_span requires a sharded pool"
         obj, bsz = self.obj, self.cfg.block_size
 
-        def run_local(state: PoolState, page_dev, lanes, pages):
+        def finalize_span(state: PoolState, page_dev, lanes, pages):
             my = axis_linear_index(("pool",))
             pages = pages[0]                          # (v, g) local ids
             xpg = state.pool[pages]                   # (v, g, block)
@@ -943,7 +950,7 @@ class PoolOps:
         # repro: allow[RPR005] read-only like finalize: the pool must stay
         # live for stepping, so no donation
         fn = jax.jit(jax.shard_map(
-            run_local, mesh=self.mesh, check_vma=False,
+            finalize_span, mesh=self.mesh, check_vma=False,
             in_specs=(_state_specs(), P(), P(),
                       P("pool", None, None)),
             out_specs=(P(), P(), P())))

@@ -103,6 +103,12 @@ from repro.objectives.base import SeparableObjective
 _NULL = contextlib.nullcontext()
 
 
+def _job_ids(pairs) -> str:
+    """The ids of (slot, job) pairs as one span arg: space-separated,
+    since the profiler's annotation encoding cuts a value at a comma."""
+    return " ".join(rec.job_id for _, rec in pairs)
+
+
 class AdmissionError(RuntimeError):
     """Typed submit() rejection (backpressure, not malformed input — a
     RuntimeError subclass so wire front-ends can keep mapping ValueError
@@ -1157,8 +1163,10 @@ class SolveEngine:
     def _step_impl(self) -> int:
         tr = self.tracer
         with tr.span("step", step=self.step_count) as step_sp:
-            with tr.span("refill"):
-                self._refill()
+            with tr.span("refill") as sp:
+                placed = self._refill()
+                if tr.enabled:
+                    sp.set(jobs=_job_ids(placed))
             finished = 0
             for pool in self.pools.values():
                 if pool.active == 0:
@@ -1214,8 +1222,13 @@ class SolveEngine:
                 for job_id in pool.job_ids:
                     if job_id is not None:
                         self.jobs[job_id].passes_done += r
-                with tr.span("harvest", family=pool.key[0]) as sp:
-                    got = self._harvest(pool, ops)
+                fins = [(slot, self.jobs[jid])
+                        for slot, jid in enumerate(pool.job_ids)
+                        if jid is not None
+                        and self.jobs[jid].passes_done >= cfg.n_passes]
+                ids = {"jobs": _job_ids(fins)} if tr.enabled else {}
+                with tr.span("harvest", family=pool.key[0], **ids) as sp:
+                    got = self._harvest(pool, ops, fins)
                     sp.set(finished=got)
                 finished += got
             self.step_count += 1
@@ -1294,11 +1307,11 @@ class SolveEngine:
         self._done_seq += 1
         return seq
 
-    def _refill(self):
+    def _refill(self) -> list[tuple[int, JobState]]:
         # Stage lane bindings + page allocations first (growing each pool's
         # capacity plan at most once), then write every pool's new lanes in
         # batched place dispatches — refilling 8 lanes costs the same host
-        # overhead as refilling one.
+        # overhead as refilling one. Returns the (slot, job) pairs placed.
         staged: dict[tuple, list[tuple[int, JobState]]] = {}
         while self.queue and self.active_lanes < self.lanes:
             job_id = self.queue.popleft()
@@ -1371,6 +1384,7 @@ class SolveEngine:
                         poisoned.append((slot, rec))
                 if poisoned:
                     self._poison(pool, ops, poisoned)
+        return [sr for placed in staged.values() for sr in placed]
 
     @staticmethod
     def _stripes(pool: LanePool, cfg: ABOConfig, spec: JobSpec) -> bool:
@@ -1623,12 +1637,12 @@ class SolveEngine:
 
     # repro: allow[RPR001] harvest is THE designed sync point: finished
     # lanes' fun/x/history are read back exactly once, off the hot loop
-    def _harvest(self, pool: LanePool, ops: batched.PoolOps) -> int:
-        cfg = batched.key_config(pool.key)
-        fins = [(slot, self.jobs[jid])
-                for slot, jid in enumerate(pool.job_ids)
-                if jid is not None
-                and self.jobs[jid].passes_done >= cfg.n_passes]
+    def _harvest(self, pool: LanePool, ops: batched.PoolOps,
+                 fins: list[tuple[int, JobState]]) -> int:
+        """Finish the lanes in ``fins`` (slot, job): one finalize
+        dispatch per gather kind, the wait for the device, the read-back,
+        then the host bookkeeping (each a span; see DESIGN.md
+        "Observability"). No finisher, no sync."""
         if not fins:
             return 0
         span_fins = [(s, r) for s, r in fins
@@ -1646,11 +1660,10 @@ class SolveEngine:
             g, v, lanes_np, pages_np = _gather_tables(
                 [(s, pool.page_table[s]) for s, _ in whole_fins],
                 pool.slots)
-            f_all, x_all, hist_all = ops.finalize(g, v)(
-                pool.state, jnp.asarray(lanes_np), jnp.asarray(pages_np))
-            with self._allowed("harvest read-back"):
-                f_np, x_np, h_np = (np.asarray(f_all), np.asarray(x_all),
-                                    np.asarray(hist_all))
+            with self.tracer.span("finalize"):
+                dev_outs = ops.finalize(g, v)(
+                    pool.state, jnp.asarray(lanes_np), jnp.asarray(pages_np))
+            f_np, x_np, h_np = self._read_back(dev_outs)
             outs += [(s, r, f_np[i], x_np[i], h_np[i])
                      for i, (s, r) in enumerate(whole_fins)]
         elif whole_fins:
@@ -1669,12 +1682,11 @@ class SolveEngine:
                 lanes_np[d, i] = slot
                 pt = pool.page_table[slot]
                 pages_np[d, i, : len(pt)] = pt
-            f_all, x_all, hist_all = ops.finalize(g, v)(
-                pool.state, jnp.asarray(row_dev), jnp.asarray(lanes_np),
-                jnp.asarray(pages_np))
-            with self._allowed("harvest read-back"):
-                f_np, x_np, h_np = (np.asarray(f_all), np.asarray(x_all),
-                                    np.asarray(hist_all))
+            with self.tracer.span("finalize"):
+                dev_outs = ops.finalize(g, v)(
+                    pool.state, jnp.asarray(row_dev), jnp.asarray(lanes_np),
+                    jnp.asarray(pages_np))
+            f_np, x_np, h_np = self._read_back(dev_outs)
             outs += [(s, r, f_np[i], x_np[i], h_np[i])
                      for i, (s, r) in enumerate(whole_fins)]
         if span_fins:
@@ -1695,12 +1707,11 @@ class SolveEngine:
                                                  pool.lane_dev[slot])):
                     page_dev[i, p] = d
                     pages_np[d, i, p] = loc
-            f_all, x_all, hist_all = ops.finalize_span(g, v)(
-                pool.state, jnp.asarray(page_dev), jnp.asarray(lanes_np),
-                jnp.asarray(pages_np))
-            with self._allowed("harvest read-back"):
-                f_np, x_np, h_np = (np.asarray(f_all), np.asarray(x_all),
-                                    np.asarray(hist_all))
+            with self.tracer.span("finalize"):
+                dev_outs = ops.finalize_span(g, v)(
+                    pool.state, jnp.asarray(page_dev), jnp.asarray(lanes_np),
+                    jnp.asarray(pages_np))
+            f_np, x_np, h_np = self._read_back(dev_outs)
             outs += [(s, r, f_np[i], x_np[i], h_np[i])
                      for i, (s, r) in enumerate(span_fins)]
         now = time.time()
@@ -1739,6 +1750,20 @@ class SolveEngine:
             if pool.shrink_to_fit():     # turnover mid-burst (phase-aligned
                 self._c_resizes.inc()    # lanes all finish together; the
         return len(fins)                 # next refill would regrow at once)
+
+    # repro: allow[RPR001] the harvest's designed sync: the wait for the
+    # device, then the read-back of the finishers' outputs
+    def _read_back(self, outs: tuple) -> tuple:
+        """``outs`` (device arrays) on the host. ``device_wait`` ends
+        when the device has finished every dispatched step and the
+        finalize; ``readback`` is the transfer alone."""
+        tr = self.tracer
+        with self._allowed("harvest read-back"):
+            with tr.span("device_wait"):
+                jax.block_until_ready(outs)
+            with tr.span("readback",
+                         bytes=sum(a.nbytes for a in outs)):
+                return tuple(np.asarray(a) for a in outs)
 
     def _gc_jobs(self):
         """Whole-record job-table GC: keep only the ``retain_done`` most
@@ -1830,10 +1855,13 @@ class SolveEngine:
     # ------------------------------------------------------------- telemetry
     def trace(self, path: str | None = None):
         """Enable pass-level span tracing (``path`` becomes the default
-        Chrome-trace export target for :meth:`trace_export`). Until this
-        is called every span is the shared null span — tracing costs one
-        attribute check per phase."""
-        self.tracer.enable(path)
+        Chrome-trace export target for :meth:`trace_export`). Each span
+        also opens a ``jax.profiler.TraceAnnotation`` named
+        ``engine.<span>``, so under a running profiler the spans land on
+        the device trace's clock. Until this is called every span is the
+        shared null span — tracing costs one attribute check per
+        phase."""
+        self.tracer.enable(path, annotate=jax.profiler.TraceAnnotation)
 
     def trace_export(self, path: str | None = None) -> str:
         """Write recorded spans as Chrome trace-event JSON (loadable in

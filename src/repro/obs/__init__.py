@@ -10,6 +10,12 @@ Three pieces, all dependency-free beyond jax (which only
 * :mod:`.trace` — a :class:`Tracer` whose spans cost nothing when
   disabled and export Chrome-trace-event JSON (Perfetto-loadable) when
   enabled via ``SolveEngine.trace(path)`` / ``solve_server --trace``.
+  Enabled by the engine, each span also opens a profiler annotation
+  ``engine.<span>`` (an injected factory, ``jax.profiler.TraceAnnotation``),
+  so under a running profiler the engine's phases sit on the device
+  trace's clock beside its executables (``jit_fused_step``,
+  ``jit_place``, ``jit_finalize``, ...; engine/DESIGN.md
+  "Observability").
 * :mod:`.roofline` — the analytic bytes-moved-per-pass model for sweep
   plans, an XLA ``cost_analysis`` cross-check, and a measured-stream
   peak-bandwidth probe; the ``engine_roofline`` bench scenario reports

@@ -2,10 +2,10 @@
 
 Disabled is the default and costs one attribute check per ``span()``
 call: the tracer hands back a module-level null span whose enter/exit
-are no-ops — no allocation, no clock read, no list append. Enabled, a
-span is two ``perf_counter_ns`` reads and one dict append; events are
-buffered in memory (capped at ``max_events``) and exported on demand as
-the Chrome trace event format::
+are no-ops — no allocation, no clock read, no list append, no
+annotation. Enabled, a span is two ``perf_counter_ns`` reads and one
+dict append; events are buffered in memory (capped at ``max_events``)
+and exported on demand as the Chrome trace event format::
 
     {"traceEvents": [{"name", "ph": "X", "ts", "dur", "pid", "tid",
                       "args"}, ...]}
@@ -13,11 +13,21 @@ the Chrome trace event format::
 which chrome://tracing and https://ui.perfetto.dev load directly —
 ``ts``/``dur`` are microseconds relative to ``enable()``.
 
+Profiler-clock mode: ``enable(annotate=factory)`` also opens
+``factory("engine." + name, **args)`` around every span, with the args
+the span was opened with (args added later by ``set`` stay in the JSON
+only). ``SolveEngine.trace`` passes ``jax.profiler.TraceAnnotation``,
+so each span lands on the profiler's host plane, on the device trace's
+clock, with its args as stats. The factory is injected, never imported
+here: this module stays stdlib-only.
+
 Span nesting is positional, not structural: a complete ("X") event whose
 ``[ts, ts+dur]`` interval contains another's is its parent in the
 viewer. The engine emits ``step`` as the parent span with the phase
 spans (``refill``, ``plan_build``, ``fused_sweep``, ``harvest``, ...)
-inside it, all on the stepping thread's ``tid``.
+inside it, all on the stepping thread's ``tid``; ``harvest`` holds
+``finalize``, ``device_wait`` and ``readback`` (engine/DESIGN.md
+"Observability").
 """
 # repro: gauge-path — stdlib-only by invariant: observing must never sync the device
 from __future__ import annotations
@@ -47,13 +57,14 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "args", "t0")
+    __slots__ = ("tracer", "name", "args", "t0", "ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self.tracer = tracer
         self.name = name
         self.args = args
         self.t0 = 0
+        self.ann = None
 
     def set(self, **args):
         """Attach/update args mid-span (shown in the viewer's detail
@@ -61,6 +72,10 @@ class _Span:
         self.args.update(args)
 
     def __enter__(self):
+        annotate = self.tracer.annotate
+        if annotate is not None:
+            self.ann = annotate("engine." + self.name, **self.args)
+            self.ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
@@ -75,6 +90,8 @@ class _Span:
                 "pid": tr.pid, "tid": threading.get_ident() & 0xFFFF,
                 "args": self.args,
             })
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         return False
 
 
@@ -88,12 +105,16 @@ class Tracer:
         self.t0_ns = 0
         self.pid = os.getpid()
         self.default_path: str | None = None
+        self.annotate = None
 
-    def enable(self, path: str | None = None):
+    def enable(self, path: str | None = None, annotate=None):
         """Start recording; ``path`` (optional) becomes the default
-        export target for :meth:`export`."""
+        export target for :meth:`export`. ``annotate`` (optional) is a
+        context-manager factory opened as ``annotate("engine." + name,
+        **args)`` around every span (see the module docstring)."""
         self.enabled = True
         self.default_path = path or self.default_path
+        self.annotate = annotate or self.annotate
         if not self.t0_ns:
             self.t0_ns = time.perf_counter_ns()
 

@@ -31,6 +31,7 @@ from repro.obs.trace import NULL_SPAN, Tracer
 CFG = ABOConfig(samples_per_pass=5, n_passes=3, block_size=8)
 
 PHASES = {"refill", "plan_build", "fused_sweep", "harvest"}
+HARVEST_KIDS = ("finalize", "device_wait", "readback")
 
 
 def _drained_engine(tracing=False, jobs=3, **kw):
@@ -139,6 +140,39 @@ def test_tracer_event_cap_and_missing_path():
         tr.export()
 
 
+def test_tracer_annotation_hook_opens_and_closes_in_order():
+    log = []
+
+    class Ann:
+        def __init__(self, name, **args):
+            self.name = name
+            log.append(("new", name, args))
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    tr = Tracer()
+    assert tr.span("off", k=1) is NULL_SPAN
+    tr.enable(annotate=Ann)
+    with tr.span("outer", step=0):
+        with tr.span("inner", passes=5, jobs="j1 j2") as sp:
+            sp.set(late=1)                 # reaches the JSON only
+    assert log == [("new", "engine.outer", {"step": 0}),
+                   ("enter", "engine.outer"),
+                   ("new", "engine.inner", {"passes": 5, "jobs": "j1 j2"}),
+                   ("enter", "engine.inner"),
+                   ("exit", "engine.inner"), ("exit", "engine.outer")]
+    assert tr.events[0]["args"] == {"passes": 5, "jobs": "j1 j2", "late": 1}
+    tr.disable()
+    assert tr.span("off") is NULL_SPAN
+    with tr.span("off"):
+        pass
+    assert len(log) == 6 and len(tr.events) == 2
+
+
 # ------------------------------------------------------- engine integration
 def test_engine_spans_and_bit_identity(tmp_path):
     eng, ids = _drained_engine(tracing=True)
@@ -156,12 +190,71 @@ def test_engine_spans_and_bit_identity(tmp_path):
     doc = json.loads(open(path).read())
     evs = doc["traceEvents"]
     steps = [e for e in evs if e["name"] == "step"]
-    inner = [e for e in evs if e["name"] in PHASES | {"resize", "snapshot"}]
+    nested = PHASES | {"resize", "snapshot"} | set(HARVEST_KIDS)
+    inner = [e for e in evs if e["name"] in nested]
     assert steps and inner
     for e in inner:
         assert any(s["ts"] <= e["ts"]
                    and e["ts"] + e["dur"] <= s["ts"] + s["dur"] + 1e-3
                    for s in steps), f"{e['name']} span not nested in a step"
+
+
+def _inside(e, outer):
+    return (outer["ts"] <= e["ts"]
+            and e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1e-3)
+
+
+def test_engine_harvest_children_carry_job_ids():
+    """A finishing harvest holds its finalize dispatch, the wait for the
+    device and the read-back, in that order; refill and harvest name the
+    jobs they placed and finished."""
+    eng, ids = _drained_engine(tracing=True)
+    evs = eng.tracer.events
+    harvests = [e for e in evs if e["name"] == "harvest"
+                and e["args"]["finished"]]
+    assert harvests
+    for h in harvests:
+        kids = [e for e in evs if e["name"] in HARVEST_KIDS
+                and _inside(e, h)]
+        assert [e["name"] for e in kids] == list(HARVEST_KIDS)
+        assert kids[-1]["args"]["bytes"] > 0
+        assert len(h["args"]["jobs"].split()) == h["args"]["finished"]
+    finished = [j for h in harvests for j in h["args"]["jobs"].split()]
+    placed = [j for e in evs if e["name"] == "refill"
+              for j in e["args"]["jobs"].split()]
+    assert sorted(finished) == sorted(placed) == sorted(ids)
+    # a harvest that finishes no job opens no child: no sync
+    n_kids = sum(e["name"] in HARVEST_KIDS for e in evs)
+    assert n_kids == len(HARVEST_KIDS) * len(harvests)
+
+
+def test_engine_spans_reach_the_profiler_trace(tmp_path):
+    """With a profiler running, each engine span is an ``engine.<name>``
+    host event on the profiler's clock, its open-time args as stats."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    eng = SolveEngine(lanes=2)
+    eng.trace()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jid = eng.submit(JobSpec("sphere", 20, CFG, seed=0))
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    host = [(e.name, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("engine.")]
+    names = [n for n, _ in host]
+    for span in ("step", "refill", "fused_sweep", "harvest") + HARVEST_KIDS:
+        assert "engine." + span in names
+    assert dict(host)["engine.harvest"]["jobs"] == jid
+    assert dict(host)["engine.readback"]["bytes"] > 0
+    assert dict(host)["engine.fused_sweep"]["passes"] == CFG.n_passes
 
 
 def test_engine_trace_default_path(tmp_path):
