@@ -119,14 +119,70 @@ def _candidate_grid(xb, lo, hi, half_width, m, is_first_pass):
     w = jnp.where(is_first_pass, 0.5 * span,
                   jnp.asarray(half_width, dt) * span)
     offs = jnp.linspace(-1.0, 1.0, m - 1, dtype=dt)          # (m-1,)
-    grid = jnp.clip(center + w * offs[None, :], lo, hi)
+    grid = jnp.clip(center + _rounded(w * offs[None, :]), lo, hi)
     return jnp.concatenate([grid, xb[:, None]], axis=1)       # (B, m)
+
+
+def _rounded(p):
+    """``p``, rounded on its own before the caller adds it to anything.
+
+    A compiler may contract ``c + a * b`` into one fused multiply-add,
+    which skips the product's rounding, and whether it does depends on
+    the program around it: XLA:CPU compiles a dense block loop of small
+    blocks as one kernel and contracts the candidate grid there, but not
+    in the engine's row loop, which puts the two a few ulps apart. The
+    product goes through its integer bits, xored with ``p != p`` (zero
+    unless ``p`` is NaN, which stays NaN), which no compiler can fold
+    away or contract through."""
+    bits = jax.lax.bitcast_convert_type(
+        p, jnp.dtype(f"int{8 * p.dtype.itemsize}"))
+    return jax.lax.bitcast_convert_type(
+        bits ^ (p != p).astype(bits.dtype), p.dtype)
+
+
+def _first_min(op, acc):
+    """``jnp.argmin``'s reducer (``lax._ArgMinMaxReducer(lt)``) over
+    ``(value, index, *payload)``: the value moves on ``lt | isnan``, the
+    index and every payload move together on that or on an equal value at
+    a lower index."""
+    (v, i, *p), (av, ai, *ap) = op, acc
+    pick_val = jax.lax.lt(v, av) | jax.lax.ne(v, v)
+    pick_idx = pick_val | (jax.lax.eq(v, av) & jax.lax.lt(i, ai))
+    return (jax.lax.select(pick_val, v, av),
+            jax.lax.select(pick_idx, i, ai),
+            *(jax.lax.select(pick_idx, a, b) for a, b in zip(p, ap)))
+
+
+def _select_first_min(f_cand, cands, delta):
+    """``(x_sel, d_sel)`` at ``jnp.argmin(f_cand, 1)``, picked inside the
+    argmin's own reduce instead of gathered after it: bit-equal to
+    ``take_along_axis`` of ``cands`` (B, m) and ``delta`` (B, m, A) at
+    that index (first minimum, a NaN first). Every operand is a (B, m)
+    array; a gather of ``delta`` made the TPU compiler relay the (B, m, A)
+    tile out with A minor, padded from A to 128 lanes, on every block.
+    The identity's index is m, above every real one, so a real
+    element wins each tie with it: a row of +inf selects its column 0,
+    never the identity's zero payload."""
+    b, m = f_cand.shape
+    iota = jax.lax.broadcasted_iota(jnp.int32, (b, m), 1)
+    deltas = [delta[..., a] for a in range(delta.shape[-1])]
+    operands = (f_cand, iota, cands, *deltas)
+    init = (jnp.array(jnp.inf, f_cand.dtype), jnp.array(m, jnp.int32),
+            *(jnp.zeros((), o.dtype) for o in operands[2:]))
+    _, _, x_sel, *d_sel = jax.lax.reduce(operands, init, _first_min, (1,))
+    return x_sel, jnp.stack(d_sel, axis=-1)
 
 
 def _block_step(obj, cfg, probe_tile, xb, aggs, idx, valid, half_width,
                 is_first_pass, lam, lo, hi):
     """Probe-and-commit one Jacobi block: the (B, m) candidate tile, the
-    argmin selection, and the guarded aggregate commit.
+    first-minimum selection, and the guarded aggregate commit.
+
+    The selection is one variadic reduce (:func:`_select_first_min`) that
+    carries the candidate and its per-aggregate deltas beside argmin's
+    (value, index) pair, bit-equal to argmin plus two gathers. On a TPU
+    v5e the gathers took two thirds of the engine's row loop: the delta
+    gather wanted a layout copy of the (B, m, A) tile, padded 42x.
 
     This is the single block-level primitive BOTH sweep layouts execute:
     :func:`_sweep_pass` scans it over a dense padded vector (abo_minimize),
@@ -141,10 +197,7 @@ def _block_step(obj, cfg, probe_tile, xb, aggs, idx, valid, half_width,
     cands = jnp.where(valid[:, None], cands, xb[:, None])
 
     f_cand, delta = probe_tile(aggs, idx, xb, cands, lam)  # (B, m), (B, m, A)
-    sel = jnp.argmin(f_cand, axis=1)                       # (B,)
-    x_sel = jnp.take_along_axis(cands, sel[:, None], axis=1)[:, 0]
-    d_sel = jnp.take_along_axis(
-        delta, sel[:, None, None], axis=1)[:, 0, :]        # (B, A)
+    x_sel, d_sel = _select_first_min(f_cand, cands, delta)  # (B,), (B, A)
     # a frozen padding coordinate's delta is t(x) - t(x), which is not 0
     # once the compiler contracts it into an FMA (the rounding error of
     # t(x)): zero it, or padding noise decides the guarded commit

@@ -449,8 +449,15 @@ class PoolOps:
                            aggs0[ln], aggs[ln])
             args = jax.lax.optimization_barrier(
                 (pool[pg], ag, idx, valid, half_width, p == 0, lam))
-            xb2, ag2 = jax.lax.optimization_barrier(
-                jax.vmap(core_step)(*args))
+            if ln.shape[0] == 1:
+                # one lane runs unbatched: vmapped, its size-1 lane axis
+                # lands second-minor in XLA:TPU's layout of the probe
+                # tile, whose (1, 128) tiles fill one sublane in eight
+                xb2, ag2 = jax.lax.optimization_barrier(jax.tree.map(
+                    lambda r: r[None], core_step(*(a[0] for a in args))))
+            else:
+                xb2, ag2 = jax.lax.optimization_barrier(
+                    jax.vmap(core_step)(*args))
             return pool.at[pg].set(xb2), aggs.at[ln].set(ag2)
 
         pool, aggs = jax.lax.fori_loop(

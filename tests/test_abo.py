@@ -143,3 +143,59 @@ def test_per_coordinate_bounds_s3():
     assert abs(r2.fun - 0.25 * n) / (0.25 * n) < 0.01
     x = np.asarray(r2.x)
     assert (x >= shift + 0.5 - 1e-5).all() and (x <= shift + 2.0 + 1e-5).all()
+
+
+# ---------------------------------------------------------------------------
+# the block step's selection: one first-minimum reduce, bit-equal to argmin
+# plus the two gathers it replaced
+# ---------------------------------------------------------------------------
+def _argmin_then_gather(f_cand, cands, delta):
+    """The selection as it was: argmin, then take_along_axis twice."""
+    sel = jnp.argmin(f_cand, axis=1)
+    x_sel = jnp.take_along_axis(cands, sel[:, None], axis=1)[:, 0]
+    d_sel = jnp.take_along_axis(delta, sel[:, None, None], axis=1)[:, 0, :]
+    return x_sel, d_sel
+
+
+def _selection_tile(kind, n_aggs, seed=0):
+    """A (B, m) probe tile with its candidates and (B, m, A) deltas; its
+    values are drawn from three levels so that random rows tie too."""
+    rng = np.random.default_rng(seed)
+    b, m = 16, 9
+    f = rng.integers(0, 3, (b, m)).astype(np.float32)
+    cands = rng.standard_normal((b, m)).astype(np.float32)
+    delta = rng.standard_normal((b, m, n_aggs)).astype(np.float32)
+    if kind == "ties":                 # the minimum at several columns
+        f[:, ::2] = -1.0
+    elif kind == "signed_zero":        # -0.0 == +0.0: the first one wins
+        f[:] = 1.0
+        f[:, 3], f[:, 5] = 0.0, -0.0
+        f[::2, 3], f[::2, 5] = -0.0, 0.0
+    elif kind == "nan":                # a NaN wins over every number
+        f[::2, 4] = np.nan
+        f[1, 2] = f[1, 6] = np.nan
+    elif kind == "all_inf":
+        f[::3] = np.inf
+    elif kind == "frozen":             # padding: every candidate is x
+        f[8:] = f[8:, :1]
+        cands[8:] = cands[8:, -1:]
+    return jnp.asarray(f), jnp.asarray(cands), jnp.asarray(delta)
+
+
+@pytest.mark.parametrize("n_aggs", [1, 2, 3])
+@pytest.mark.parametrize("kind", ["random", "ties", "signed_zero", "nan",
+                                  "all_inf", "frozen"])
+def test_first_min_selection_is_argmin_then_gather(kind, n_aggs):
+    import jax
+    from repro.core.abo import _select_first_min
+
+    tile = _selection_tile(kind, n_aggs)
+    want = jax.jit(_argmin_then_gather)(*tile)
+    got = jax.jit(_select_first_min)(*tile)
+    lanes = jax.jit(jax.vmap(_select_first_min))(   # the engine's context
+        *(jnp.stack([a, a[::-1]]) for a in tile))
+    for w, g, (lane0, lane1) in zip(want, got, lanes):
+        bits = np.asarray(w).view(np.uint32)
+        assert np.array_equal(np.asarray(g).view(np.uint32), bits)
+        assert np.array_equal(np.asarray(lane0).view(np.uint32), bits)
+        assert np.array_equal(np.asarray(lane1[::-1]).view(np.uint32), bits)

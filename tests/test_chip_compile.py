@@ -162,6 +162,34 @@ def test_main_path_sums_in_fixed_order(one_chip):
     assert _float_sums(fused.as_text()) == []
 
 
+def _dims(shape: str) -> list[int]:
+    return [int(d) for d in shape.split(",") if d]
+
+
+def test_row_sweep_selects_without_copy_or_gather(one_chip):
+    """The unsharded fused step at the benchmark's one-chip shape (n = 5e7,
+    m = 51, block 4096, 8 lanes) picks each block's candidate and deltas
+    inside the argmin's reduce. Gathered after it, the deltas made the
+    compiler copy the (1, 4096, 51, 3) probe tile into a layout with the
+    aggregate axis minor, padded from 3 to 128 lanes, on every block row;
+    the only gather left is the sync's gather of the lane's page rows.
+    The one lane's probe tile is tiled (8, 128), not (1, 128), which
+    fills one sublane in eight."""
+    m, n_aggs = 51, 3
+    pool = _one_lane_pool(5 * 10 ** 7, ABOConfig(samples_per_pass=m))
+    text = _compile_fused(pool, batched.PoolState(*(one_chip,) * 5),
+                          lambda a: _shape(a, one_chip)).as_text()
+    shapes = dict(re.findall(r"(%[\w.\-]+) = \w+\[([\d,]*)\]", text))
+    copies = [_dims(s) for s in re.findall(
+        r"= \w+\[([\d,]*)\]\S* copy\(", text)]
+    assert copies and not [d for d in copies if {m, n_aggs} <= set(d)]
+    gathers = re.findall(r"= (\w+\[[\d,]*\])\S* gather\((%[\w.\-]+),", text)
+    assert [g for g, _ in gathers] == ["f32[12288,4096]"]
+    assert not [a for _, a in gathers if m in _dims(shapes[a])]
+    sparse = re.findall(r"f32\[([\d,]*)\]\{[\d,]*:T\(1,128\)", text)
+    assert not [d for d in sparse if m in _dims(d)]
+
+
 def test_placement_writes_pages_without_scatter(one_chip):
     """Placing one n = 1e6 lane writes its 256 rung-padded page rows (245
     real, 11 on the scratch page) one dynamic_update_slice at a time. As

@@ -93,6 +93,26 @@ def test_padded_lane_bit_identical_off_grid(obj, n, m):
     np.testing.assert_array_equal(np.asarray(got.x), np.asarray(ref.x))
 
 
+@pytest.mark.parametrize("block,m", [(2, 5), (4, 7), (8, 7)])
+def test_small_block_loop_bit_identical(block, m):
+    """At blocks this small XLA:CPU compiles abo_minimize's block loop as
+    one kernel and may contract the candidate grid's ``center + w * offs``
+    into a fused multiply-add there, while the engine's row loop rounds
+    the product first: the grid must round it in every program, or x
+    comes apart by ulps."""
+    cfg = ABOConfig(samples_per_pass=m, n_passes=4, block_size=block)
+    specs = [JobSpec("sphere", 40 + 17 * i, cfg, seed=i) for i in range(7)]
+    eng = SolveEngine(lanes=3)
+    ids = eng.submit_many(specs)
+    eng.run()
+    for spec, jid in zip(specs, ids):
+        got = eng.result(jid)
+        ref = abo_minimize(OBJECTIVES["sphere"], spec.n, config=cfg,
+                           seed=spec.seed)
+        assert got.fun == ref.fun, spec.n
+        np.testing.assert_array_equal(np.asarray(got.x), np.asarray(ref.x))
+
+
 def test_submit_poll_cancel_lifecycle():
     # max_fuse=1: strict pass-per-step, so a job is observably RUNNING
     eng = SolveEngine(lanes=1, max_fuse=1)
