@@ -166,10 +166,19 @@ def _select_first_min(f_cand, cands, delta):
     b, m = f_cand.shape
     iota = jax.lax.broadcasted_iota(jnp.int32, (b, m), 1)
     deltas = [delta[..., a] for a in range(delta.shape[-1])]
-    operands = (f_cand, iota, cands, *deltas)
+    # XLA refuses a reduce over floats of two widths: a candidate narrower
+    # than the values (float32 x beside float64 aggregates) rides as its
+    # bit pattern and is cast back after the reduce
+    narrow = cands.dtype != f_cand.dtype
+    payload = (jax.lax.bitcast_convert_type(
+        cands, jnp.dtype(f"int{8 * cands.dtype.itemsize}")) if narrow
+        else cands)
+    operands = (f_cand, iota, payload, *deltas)
     init = (jnp.array(jnp.inf, f_cand.dtype), jnp.array(m, jnp.int32),
             *(jnp.zeros((), o.dtype) for o in operands[2:]))
     _, _, x_sel, *d_sel = jax.lax.reduce(operands, init, _first_min, (1,))
+    if narrow:
+        x_sel = jax.lax.bitcast_convert_type(x_sel, cands.dtype)
     return x_sel, jnp.stack(d_sel, axis=-1)
 
 

@@ -112,6 +112,12 @@ SCRATCH_PAGE = 0
 # the identical trajectory as before.
 SPAN_NONE_ROWS = 1 << 30
 
+# The pass-end aggregate re-sync's name scope: every op it compiles to
+# carries ``engine.resync`` in its metadata's op name, which a TPU
+# profile keeps as each op's ``tf_op``, so a profile tells the re-sync's
+# device time apart from the row sweep's inside the one fused step.
+RESYNC_SCOPE = "engine.resync"
+
 
 def pad_ladder(n: int, block: int,
                max_pad_waste: float = DEFAULT_MAX_PAD_WASTE) -> int:
@@ -243,8 +249,8 @@ def write_pages(pool, pages, rows):
     rows = rows.reshape(pages.shape[0], pool.shape[1]).astype(pool.dtype)
 
     def body(i, pool):
-        return jax.lax.dynamic_update_slice(pool, rows[i][None],
-                                            (pages[i], 0))
+        return jax.lax.dynamic_update_slice(
+            pool, rows[i][None], (pages[i], jnp.zeros((), pages.dtype)))
 
     return jax.lax.fori_loop(0, pages.shape[0], body, pool)
 
@@ -614,7 +620,8 @@ class PoolOps:
                     aggs0 = st.aggs
                     for ba in band_args:
                         st = self._band_body(st, *ba, shard_rows, aggs0)
-                    return self._sync_body(st, *sync_args)
+                    with jax.named_scope(RESYNC_SCOPE):
+                        return self._sync_body(st, *sync_args)
 
                 return jax.lax.fori_loop(0, n_fused, one_pass, state)
 
@@ -641,11 +648,12 @@ class PoolOps:
                     aggs0 = st.aggs
                     for ba in band_args:
                         st = self._band_body(st, *ba, shard_rows, aggs0)
-                    st = self._sync_body(st, *sync_args)
-                    if span is not None:
-                        st = self._span_sync(st, vs, t_pad, sp_lanes,
-                                             sp_ntiles, tile_slot, tile_idx,
-                                             tile_pages, tile_off)
+                    with jax.named_scope(RESYNC_SCOPE):
+                        st = self._sync_body(st, *sync_args)
+                        if span is not None:
+                            st = self._span_sync(
+                                st, vs, t_pad, sp_lanes, sp_ntiles,
+                                tile_slot, tile_idx, tile_pages, tile_off)
                     # ONE exchange per pass: every slot's scalars from
                     # their single writer (bit patterns, not float sums)
                     return dataclasses.replace(

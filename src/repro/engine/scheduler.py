@@ -1204,7 +1204,8 @@ class SolveEngine:
                 # dispatch, not device completion, for the same reason)
                 with tr.span("fused_sweep", family=pool.key[0], passes=r,
                              swept_rows=plan.swept_slots,
-                             est_bytes=r * plan.pass_bytes):
+                             est_bytes=r * plan.pass_bytes,
+                             agg_bytes=pool.state.aggs.dtype.itemsize):
                     prev = pool.state if self.sanitize else None
                     pool.state = ops.fused_step(*plan.signature())(
                         pool.state, self._r_const(r), *plan.args)

@@ -10,8 +10,13 @@ a fixture, never at import: only one process may hold the TPU library at
 a time, and every test worker imports this file.
 """
 import dataclasses
+import json
 import os
+import pathlib
 import re
+import subprocess
+import sys
+import textwrap
 
 import numpy as np
 import pytest
@@ -30,6 +35,7 @@ from repro.kernels.griewank.kernel import griewank_aggregates_kernel
 from repro.objectives import GRIEWANK
 
 PAPER = ABOConfig()                  # m = 50 samples x 5 passes, block 4096
+REPO = pathlib.Path(__file__).resolve().parent.parent
 HBM_BYTES = 16 * 2 ** 30             # one v5e chip
 
 
@@ -185,6 +191,66 @@ def test_row_sweep_selects_without_copy_or_gather(one_chip):
     assert copies and not [d for d in copies if {m, n_aggs} <= set(d)]
     gathers = re.findall(r"= (\w+\[[\d,]*\])\S* gather\((%[\w.\-]+),", text)
     assert [g for g, _ in gathers] == ["f32[12288,4096]"]
+    assert not [a for _, a in gathers if m in _dims(shapes[a])]
+    sparse = re.findall(r"f32\[([\d,]*)\]\{[\d,]*:T\(1,128\)", text)
+    assert not [d for d in sparse if m in _dims(d)]
+
+
+def test_row_sweep_f64_aggregates_selects_without_copy_or_gather(tmp_path):
+    """The fused step in the float64-aggregate mode at the one-chip
+    float64 cell's shape (``bench/configs/abo_griewank_paper_f64.json``:
+    m = 51, block 4096, 8 lanes): x stays float32, the aggregates, probe
+    values and deltas are float64, which the v5e has no unit for and
+    XLA:TPU emulates as pairs of float32. The row loop still copies no
+    probe tile with the m axis, keeps no one-sublane tile with it, and
+    its only gather is the sync's. x64 is process-wide, so the compile
+    runs in a child process, which writes the compiled text."""
+    cfg_path = REPO / "bench" / "configs" / "abo_griewank_paper_f64.json"
+    n = json.loads(cfg_path.read_text())["job"]["n"]
+    hlo = tmp_path / "fused_step.txt"
+    env = {**os.environ, "JAX_ENABLE_X64": "1", "JAX_PLATFORMS": "cpu",
+           "TPU_LOG_DIR": "disabled", "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+           "PYTHONPATH": str(REPO / "src")}
+    script = textwrap.dedent(f"""
+        import pathlib, sys
+        sys.path.insert(0, {str(REPO / "tests")!r})
+        import jax
+        from jax.sharding import SingleDeviceSharding
+        from jax.experimental import topologies
+        import test_chip_compile as t
+        from repro.core.abo import ABOConfig
+        from repro.engine import batched
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:   # noqa: BLE001 — any failure means skip
+            print("skip:", e)
+            raise SystemExit(0)
+        one = SingleDeviceSharding(topo.devices[0])
+        pool = t._one_lane_pool({n}, ABOConfig(samples_per_pass=51))
+        state = jax.eval_shape(lambda: batched.zeros_pool_state(
+            t.GRIEWANK, pool.key, pool.slots, pool.capacity))
+        assert state.aggs.dtype == "float64", state.aggs.dtype
+        pathlib.Path({str(hlo)!r}).write_text(t._compile_fused(
+            pool, batched.PoolState(*(one,) * 5),
+            lambda a: t._shape(a, one)).as_text())
+    """)
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=900)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    if proc.stdout.startswith("skip:"):
+        pytest.skip(f"no v5e:2x2 topology can be described here: "
+                    f"{proc.stdout}")
+    text = hlo.read_text()
+    m, n_aggs = 51, 3
+    rows = batched.pad_ladder(batched.pages_for(n, 4096), 1)
+    assert "f64[" in text
+    shapes = dict(re.findall(r"(%[\w.\-]+) = \w+\[([\d,]*)\]", text))
+    copies = [_dims(s) for s in re.findall(
+        r"= \w+\[([\d,]*)\]\S* copy\(", text)]
+    assert copies and not [d for d in copies if {m, n_aggs} <= set(d)]
+    gathers = re.findall(r"= (\w+\[[\d,]*\])\S* gather\((%[\w.\-]+),", text)
+    assert [g for g, _ in gathers] == [f"f32[{rows},4096]"]
     assert not [a for _, a in gathers if m in _dims(shapes[a])]
     sparse = re.findall(r"f32\[([\d,]*)\]\{[\d,]*:T\(1,128\)", text)
     assert not [d for d in sparse if m in _dims(d)]

@@ -88,3 +88,44 @@ def test_pool_ops_lower_to_named_modules(devices):
     modules = json.loads(out.stdout.strip().splitlines()[-1])
     assert modules == NAMES[devices]
     assert not any(m.startswith("jit_run") for m in modules)
+
+
+def test_resync_ops_carry_their_name_scope():
+    """Inside the fused step, the pass-end re-sync compiles under the name
+    scope ``batched.RESYNC_SCOPE``: its ops, the tile loop among them,
+    carry it in their metadata's op name, which the device trace keeps
+    as each op's ``tf_op``. The row sweep's selection does not."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import ABOConfig
+    from repro.engine import batched
+    from repro.engine.scheduler import LanePool
+    from repro.objectives import GRIEWANK
+
+    n, cfg = 9_000, ABOConfig(samples_per_pass=5, n_passes=2,
+                              block_size=1024)
+    key = batched.family_key("griewank", n, cfg)
+    pool = LanePool(key=key, obj=GRIEWANK, lanes=2)
+    slot = pool.take_slot()
+    pool.job_ids[slot] = "job0"
+    pool.lane_dev[slot] = 0
+    pool.page_table[slot] = pool.alloc_pages(
+        batched.pages_for(n, cfg.block_size), 0)
+    plan = pool.build_plan()
+    ops = batched.PoolOps(GRIEWANK, key, pool.slots, pool.capacity)
+    state = jax.eval_shape(lambda: batched.zeros_pool_state(
+        GRIEWANK, key, pool.slots, pool.capacity))
+    text = ops.fused_step(*plan.signature()).lower(
+        state, jax.ShapeDtypeStruct((), jnp.int32),
+        *plan.args).compile().as_text()
+    names = re.findall(r" (\w[\w-]*)\(.*op_name=\"([^\"]+)\"", text)
+    scoped = {op for op, name in names if batched.RESYNC_SCOPE in name}
+    assert batched.RESYNC_SCOPE == "engine.resync"
+    assert "while" in scoped
+    # the selection's variadic reduce is the row sweep's, outside it
+    selection = [name for _, name in names if name.endswith("/reduce")]
+    assert selection
+    assert not [n for n in selection if batched.RESYNC_SCOPE in n]

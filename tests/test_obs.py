@@ -255,6 +255,7 @@ def test_engine_spans_reach_the_profiler_trace(tmp_path):
     assert dict(host)["engine.harvest"]["jobs"] == jid
     assert dict(host)["engine.readback"]["bytes"] > 0
     assert dict(host)["engine.fused_sweep"]["passes"] == CFG.n_passes
+    assert dict(host)["engine.fused_sweep"]["agg_bytes"] == 4   # float32
 
 
 def test_engine_trace_default_path(tmp_path):
