@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.objectives.base import SeparableObjective
+from repro.objectives.pair_expm1 import expm1_f64
 
 # |cos| is clamped before the log so that removing a term (agg - log|cos|)
 # never produces inf - inf. exp(-103) == 0 in fp32 anyway, so the clamp is
@@ -42,6 +43,15 @@ def _terms(idx: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     return jnp.stack([x * x * (1.0 / 4000.0), log_abs, neg], axis=-1)
 
 
+def _combine_f64(s, log_p, positive, lam) -> jnp.ndarray:
+    """f_λ from float64 aggregates, both signs from one ``q = expm1(L)``
+    computed in float32 pairs (:mod:`repro.objectives.pair_expm1`): a
+    TPU v5e has no float64 unit, and XLA's emulated float64 ``expm1``
+    cost more than the rest of the probe together."""
+    q = expm1_f64(log_p)
+    return jnp.where(positive, s - lam * q, s + lam * (q + 2.0))
+
+
 def _combine(aggs: jnp.ndarray) -> jnp.ndarray:
     """f = S − (−1)^K · exp(L) + 1 from aggs = [S, L, K].
 
@@ -50,6 +60,8 @@ def _combine(aggs: jnp.ndarray) -> jnp.ndarray:
     """
     s, log_p, k = aggs[..., 0], aggs[..., 1], aggs[..., 2]
     positive = jnp.mod(k, 2.0) < 0.5
+    if log_p.dtype == jnp.float64:
+        return _combine_f64(s, log_p, positive, 1.0)
     return jnp.where(positive,
                      s - jnp.expm1(log_p),
                      s + jnp.exp(log_p) + 1.0)
@@ -60,6 +72,8 @@ def _combine_relaxed(aggs: jnp.ndarray, lam) -> jnp.ndarray:
     f_1 = f exactly, and f_λ(x*) = 0 for every λ."""
     s, log_p, k = aggs[..., 0], aggs[..., 1], aggs[..., 2]
     positive = jnp.mod(k, 2.0) < 0.5
+    if log_p.dtype == jnp.float64:
+        return _combine_f64(s, log_p, positive, lam)
     return jnp.where(positive,
                      s - lam * jnp.expm1(log_p),
                      s + lam * (jnp.exp(log_p) + 1.0))

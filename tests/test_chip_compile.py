@@ -196,18 +196,17 @@ def test_row_sweep_selects_without_copy_or_gather(one_chip):
     assert not [d for d in sparse if m in _dims(d)]
 
 
-def test_row_sweep_f64_aggregates_selects_without_copy_or_gather(tmp_path):
-    """The fused step in the float64-aggregate mode at the one-chip
-    float64 cell's shape (``bench/configs/abo_griewank_paper_f64.json``:
-    m = 51, block 4096, 8 lanes): x stays float32, the aggregates, probe
-    values and deltas are float64, which the v5e has no unit for and
-    XLA:TPU emulates as pairs of float32. The row loop still copies no
-    probe tile with the m axis, keeps no one-sublane tile with it, and
-    its only gather is the sync's. x64 is process-wide, so the compile
-    runs in a child process, which writes the compiled text."""
-    cfg_path = REPO / "bench" / "configs" / "abo_griewank_paper_f64.json"
-    n = json.loads(cfg_path.read_text())["job"]["n"]
-    hlo = tmp_path / "fused_step.txt"
+F64_CONFIG = REPO / "bench" / "configs" / "abo_griewank_paper_f64.json"
+
+
+@pytest.fixture(scope="module")
+def f64_fused_text(tmp_path_factory):
+    """The compiled text of the fused step in the float64-aggregate mode
+    at the one-chip float64 cell's shape (``F64_CONFIG``: m = 51, block
+    4096, 8 lanes). x64 is process-wide, so the compile runs in a child
+    process, which writes the text."""
+    n = json.loads(F64_CONFIG.read_text())["job"]["n"]
+    hlo = tmp_path_factory.mktemp("f64") / "fused_step.txt"
     env = {**os.environ, "JAX_ENABLE_X64": "1", "JAX_PLATFORMS": "cpu",
            "TPU_LOG_DIR": "disabled", "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
            "PYTHONPATH": str(REPO / "src")}
@@ -241,7 +240,18 @@ def test_row_sweep_f64_aggregates_selects_without_copy_or_gather(tmp_path):
     if proc.stdout.startswith("skip:"):
         pytest.skip(f"no v5e:2x2 topology can be described here: "
                     f"{proc.stdout}")
-    text = hlo.read_text()
+    return hlo.read_text()
+
+
+def test_row_sweep_f64_aggregates_selects_without_copy_or_gather(
+        f64_fused_text):
+    """In the float64-aggregate mode x stays float32, the aggregates,
+    probe values and deltas are float64, which the v5e has no unit for
+    and XLA:TPU emulates as pairs of float32. The row loop still copies
+    no probe tile with the m axis, keeps no one-sublane tile with it, and
+    its only gather is the sync's."""
+    text = f64_fused_text
+    n = json.loads(F64_CONFIG.read_text())["job"]["n"]
     m, n_aggs = 51, 3
     rows = batched.pad_ladder(batched.pages_for(n, 4096), 1)
     assert "f64[" in text
@@ -254,6 +264,20 @@ def test_row_sweep_f64_aggregates_selects_without_copy_or_gather(tmp_path):
     assert not [a for _, a in gathers if m in _dims(shapes[a])]
     sparse = re.findall(r"f32\[([\d,]*)\]\{[\d,]*:T\(1,128\)", text)
     assert not [d for d in sparse if m in _dims(d)]
+
+
+def test_row_sweep_f64_probe_takes_pair_expm1(f64_fused_text):
+    """The float64 probe takes Griewank's ``expm1`` from float32 pairs
+    (``objectives.pair_expm1``), not from XLA:TPU's emulated float64
+    ``expm1``: no op of the program comes from an ``expm1`` or ``exp``,
+    and the probe's fusion, the largest, holds under 1,000 ops. With
+    ``jnp.expm1`` it held 1,558, 1,139 of them from ``expm1``; the pair
+    routine's holds 861."""
+    text = f64_fused_text
+    assert not re.findall(r'op_name="[^"]*/(?:expm1|exp)"', text)
+    bodies = re.findall(r"\n%fused_computation[\w.\-]* [^\n]*\{\n(.*?)\n\}",
+                        text, re.S)
+    assert bodies and max(b.count(" = ") for b in bodies) < 1000
 
 
 def test_placement_writes_pages_without_scatter(one_chip):
